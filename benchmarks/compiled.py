@@ -1,54 +1,29 @@
-"""Compiled-mode benchmark support: platform selection + XLA flags.
+"""Compiled-mode benchmark support: platform selection.
 
-Every trajectory point recorded so far is interpret-mode on a shared CPU
-(~1 Mb/s); the paper's regime is compiled kernels on real hardware
-(Gb/s). This module is the switch between the two worlds: it configures
-JAX for whatever real backend the machine has (the platform/XLA-flag
-idiom of the bayespec exemplar in SNIPPETS.md — ``jax_platform_name``
-config plus the GPU latency-hiding XLA flags) and reports whether an
-accelerator actually exists, so ``throughput.py --compiled`` and
-``bench_gate.py`` (``BENCH_COMPILED=1``) can no-op gracefully — exit 0
-with a clear notice — on CPU-only runners instead of recording a
-"compiled" point that is really the interpreter.
+Every trajectory point recorded so far is interpret-mode on a shared CPU;
+the paper's regime is compiled kernels on the chip. This module is the
+switch between the two worlds: it configures JAX for whatever backend the
+machine has and reports whether a TPU actually exists, so
+``throughput.py --compiled`` and ``bench_gate.py`` (``BENCH_COMPILED=1``)
+can no-op — exit 0 with a clear notice — on CPU-only runners instead of
+recording a "compiled" point that is really the interpreter.
 
 Compiled runs need no schema of their own: every trajectory run is
 stamped with ``trajectory.platform()`` (backend + device kind +
 jax_version) and the regression gate only compares same-platform runs,
-so a GPU trajectory and the interpret-CPU trajectory live side by side
+so a TPU trajectory and the interpret-CPU trajectory live side by side
 in one BENCH_kernels.json and gate independently.
 """
 from __future__ import annotations
 
-import os
-
-__all__ = ["GPU_XLA_FLAGS", "set_platform", "accelerator"]
-
-#: XLA flags for compiled GPU benching (the bayespec exemplar set):
-#: triton fusion/gemm, async collectives, and the latency-hiding
-#: scheduler — the knobs that matter for launch-bound kernels like a
-#: per-stage trellis scan. Applied via ``os.environ.setdefault`` so a
-#: user's explicit XLA_FLAGS always wins.
-GPU_XLA_FLAGS = " ".join((
-    "--xla_gpu_enable_triton_softmax_fusion=true",
-    "--xla_gpu_triton_gemm_any=True",
-    "--xla_gpu_enable_async_collectives=true",
-    "--xla_gpu_enable_latency_hiding_scheduler=true",
-    "--xla_gpu_enable_highest_priority_async_stream=true",
-))
+__all__ = ["set_platform", "accelerator"]
 
 
 def set_platform(platform: str | None = None) -> str:
     """Configure JAX for compiled benchmarking and return the backend
-    that is actually in effect.
-
-    ``platform`` forces a backend (``'gpu'``/``'tpu'``/``'cpu'``, e.g.
-    from ``BENCH_PLATFORM``); None lets JAX pick its default — a real
-    accelerator when one exists, else CPU. For GPU targets the XLA
-    flags must land in the environment BEFORE the backend initializes,
-    so call this before any jax array op (the benchmark CLIs call it
-    first thing in compiled mode)."""
-    if platform in ("gpu", "cuda"):
-        os.environ.setdefault("XLA_FLAGS", GPU_XLA_FLAGS)
+    that is actually in effect. ``platform`` forces a backend (``'tpu'``/
+    ``'cpu'``, e.g. from ``BENCH_PLATFORM``); None lets JAX pick its
+    default — the TPU when one exists, else CPU."""
     import jax
     if platform:
         jax.config.update("jax_platform_name", platform)
@@ -56,9 +31,8 @@ def set_platform(platform: str | None = None) -> str:
 
 
 def accelerator() -> str | None:
-    """The real-hardware backend name (``'gpu'``/``'tpu'``/...), or None
-    when only CPU is available — the "should compiled mode run at all?"
-    predicate."""
+    """The real-hardware backend name (``'tpu'``), or None when only CPU
+    is available — the "should compiled mode run at all?" predicate."""
     import jax
     backend = jax.default_backend()
     return None if backend == "cpu" else backend

@@ -93,7 +93,8 @@ def unified_vs_split(n=80_000):
     llr = jnp.asarray(rng.standard_normal((n, 2)).astype(np.float32))
     frames = frame_llr(llr, spec)
     rows = []
-    for unified in (True, False):
+    # the split kernel runs in interpret mode only
+    for unified in ((True,) if COMPILED else (True, False)):
         fn = jax.jit(lambda fr: ops.viterbi_decode_frames(
             fr, STD_K7, spec, unified=unified, interpret=_interpret()))
         dt = _time(fn, frames, reps=1)
@@ -131,6 +132,13 @@ def kernel_sweep(full: bool = False):
             (True, 4, 32, "sublane", "float32"),
             (True, 4, "auto", "sublane", "float32"),
             (True, 4, 32, "sublane", "bfloat16")]        # compressed BMs
+    if COMPILED:
+        # the rows the chip can run: the sublane layout, float32 metrics,
+        # tiles Mosaic accepts (kernels/viterbi_unified.check_compiled)
+        from repro.kernels.autotune import tile_ok
+        grid = [g for g in grid if g[3] == "sublane" and g[4] == "float32"
+                and (g[2] == "auto"
+                     or tile_ok(g[3], g[2], frames.shape[0]))]
     rows = []
     for pack, radix, ft, layout, bm_dtype in grid:
         fn = jax.jit(lambda fr, p=pack, r=radix, t=ft, lay=layout,
@@ -632,8 +640,10 @@ def _cli(argv=None):
     ap.add_argument("--compiled", action="store_true",
                     help="compile the Pallas kernels for the real backend "
                          "instead of interpreting them (benchmarks/"
-                         "compiled.py sets the platform + XLA flags; "
-                         "BENCH_PLATFORM forces a backend). On a CPU-only "
+                         "compiled.py sets the platform; BENCH_PLATFORM "
+                         "forces a backend; JAX's compilation cache goes "
+                         "to JAX_COMPILATION_CACHE_DIR or <checkout>/"
+                         ".jax_cache). On a CPU-only "
                          "machine this prints a notice and exits 0 — "
                          "there is nothing honest to record")
     args = ap.parse_args(argv)
@@ -647,6 +657,8 @@ def _cli(argv=None):
     if not names:
         ap.error("--sections selected nothing")
     if args.compiled:
+        from repro.compile_cache import use_compile_cache
+        use_compile_cache()        # before anything compiles
         try:                       # script (benchmarks/ on path) or package
             import compiled as _compiled
         except ImportError:

@@ -3,12 +3,13 @@
 PYTHONPATH=src python examples/quickstart.py
 
 DecoderConfig knobs beyond the defaults shown here:
-  * layout='sublane'     — Mosaic-native survivor layout (frames on the
-    128 TPU lanes, flat stage-major scratches): bit-identical, and the
-    form whose 32x survivor packing survives compiled-mode lane padding.
+  * layout='sublane'     — the survivor layout Mosaic compiles (frames on
+    the 128 TPU lanes, flat stage-major scratches): bit-identical, and
+    the default on a TPU; 'lane' runs in interpret mode only.
   * bm_dtype='bfloat16'  — store the eq.-9 branch metrics compressed
-    (fp32 path-metric accumulation). Halves the second-largest VMEM term;
-    BER within 1e-3 of float32 at Eb/N0 >= 2 dB (tests/test_ber.py).
+    (fp32 path-metric accumulation), interpret mode only. Halves the
+    second-largest VMEM term; BER within 1e-3 of float32 at Eb/N0 >= 2 dB
+    (tests/test_ber.py).
   * frames_per_tile='auto' (default) budgets whichever kernel/layout/
     dtype combination actually runs (kernels/autotune.plan_tiles).
 
@@ -43,7 +44,8 @@ tx = bpsk(encode(bits, STD_K7).reshape(-1))
 rx = awgn(jax.random.PRNGKey(1), tx, 3.0)
 
 # receiver: the paper's unified kernel (forward + parallel traceback in one
-# Pallas kernel, survivor paths in VMEM only), interpret=True on CPU
+# Pallas kernel, survivor paths in VMEM only) — compiled on a TPU,
+# interpreted elsewhere (DecoderConfig's platform defaults)
 cfg = DecoderConfig(spec=FrameSpec(f=256, v1=20, v2=45, f0=32, v2s=45),
                     backend="kernel")
 decode = make_decoder(cfg)

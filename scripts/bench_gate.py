@@ -28,7 +28,7 @@ run is recorded as the first baseline.
 
 Env knobs: BENCH_GATE_TOL=0.2 (fractional regression allowed),
 BENCH_PATH=BENCH_kernels.json, BENCH_COMPILED=1 (compiled-mode gate),
-BENCH_PLATFORM=gpu|tpu (force the compiled backend).
+BENCH_PLATFORM=tpu|cpu (force the compiled backend).
 """
 from __future__ import annotations
 

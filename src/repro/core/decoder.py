@@ -10,13 +10,14 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .metrics import branch_metrics_half, expand_half
 from .trellis import Trellis
 
 __all__ = ["viterbi_forward", "viterbi_traceback", "viterbi_decode"]
 
-NEG = jnp.float32(-1e30)   # "minus infinity" for unreachable-ish inits
+NEG = np.float32(-1e30)    # "minus infinity" for unreachable-ish inits
 
 
 def viterbi_forward(llr: jax.Array, trellis: Trellis,

@@ -17,17 +17,27 @@ from .trellis import Trellis
 __all__ = ["branch_metrics_full", "branch_metrics_half", "expand_half"]
 
 
+def _signed_sums(llr: jax.Array, signs) -> jax.Array:
+    """(n, beta) llr x (m, beta) ±1 table -> (n, m): sum_b signs[o, b] *
+    llr[:, b], added left to right in float32. The products are exact, so
+    every platform computes the same bits — a matmul would not: on a TPU
+    its default precision rounds the inputs to bfloat16."""
+    llr = llr.astype(jnp.float32)
+    out = llr[..., None, 0] * signs[:, 0]
+    for b in range(1, signs.shape[1]):
+        out = out + llr[..., None, b] * signs[:, b]
+    return out
+
+
 def branch_metrics_full(llr: jax.Array, trellis: Trellis) -> jax.Array:
     """(n, beta) llr -> (n, 2^beta) metrics for every output word (eq. 7)."""
-    signs = jnp.asarray(trellis.out_signs)            # (2^beta, beta)
-    return llr.astype(jnp.float32) @ signs.T          # (n, 2^beta)
+    return _signed_sums(llr, trellis.out_signs)       # (n, 2^beta)
 
 
 def branch_metrics_half(llr: jax.Array, trellis: Trellis) -> jax.Array:
     """(n, beta) llr -> (n, 2^(beta-1)) compressed metrics (eqs. 8-9)."""
     half = 1 << (trellis.beta - 1)
-    signs = jnp.asarray(trellis.out_signs[:half])     # (2^(beta-1), beta)
-    return llr.astype(jnp.float32) @ signs.T
+    return _signed_sums(llr, trellis.out_signs[:half])
 
 
 def expand_half(bm_half: jax.Array, trellis: Trellis) -> jax.Array:

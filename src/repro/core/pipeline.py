@@ -20,7 +20,13 @@ from .puncture import depuncture, check_alignment
 from .sanitize import LLR_CLIP as _LLR_CLIP
 from .trellis import Trellis, STD_K7
 
-__all__ = ["DecoderConfig", "make_decoder", "make_frame_decoder"]
+__all__ = ["DecoderConfig", "make_decoder", "make_frame_decoder", "on_tpu"]
+
+
+def on_tpu() -> bool:
+    """Whether the platform defaults (DecoderConfig, ops) pick the
+    compiled kernel: true exactly when JAX's default backend is a TPU."""
+    return jax.default_backend() == "tpu"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -34,9 +40,14 @@ class DecoderConfig:
     (set radix=2, pack_survivors=False, frames_per_tile=8 for the seed
     kernel behavior).
 
-    ``layout`` picks the survivor-memory orientation ('lane' = frames on
-    sublanes, the interpret-mode layout; 'sublane' = frames on lanes, the
-    Mosaic-native layout whose packing survives hardware lane padding) —
+    ``backend``, ``interpret`` and ``layout`` default from the platform
+    (``jax.default_backend()`` when the config is built): on a TPU the
+    compiled unified kernel in the sublane layout (``'kernel'``, False,
+    ``'sublane'``); elsewhere the pure-JAX reference with the Pallas
+    kernels, when asked for, interpreted in the lane layout
+    (``'reference'``, True, ``'lane'``). ``layout`` picks the
+    survivor-memory orientation ('lane' = frames on sublanes, interpret
+    mode only; 'sublane' = frames on lanes, the layout Mosaic compiles) —
     still bit-exact. ``bm_dtype='bfloat16'`` stores branch metrics
     compressed with float32 path-metric accumulation: the one knob that is
     NOT bit-exact, but BER-neutral to within 1e-3 at Eb/N0 >= 2 dB
@@ -62,12 +73,17 @@ class DecoderConfig:
     trellis: Trellis = STD_K7
     spec: FrameSpec = FrameSpec()
     rate: str = "1/2"
-    backend: str = "reference"     # 'reference' | 'kernel' | 'kernel_split'
-    interpret: bool = True         # Pallas interpret mode (CPU container)
+    # 'reference' | 'kernel' | 'kernel_split'
+    backend: str = dataclasses.field(
+        default_factory=lambda: "kernel" if on_tpu() else "reference")
+    # Pallas interpret mode: off only where the kernels compile
+    interpret: bool = dataclasses.field(default_factory=lambda: not on_tpu())
     pack_survivors: bool = True    # bit-pack survivors 32x (kernel backends)
     radix: int = 4                 # 2 | 4 trellis stages per ACS step
     frames_per_tile: int | str = "auto"   # tile size, or VMEM-planned
-    layout: str = "lane"           # 'lane' | 'sublane' survivor layout
+    # 'lane' | 'sublane' survivor layout
+    layout: str = dataclasses.field(
+        default_factory=lambda: "sublane" if on_tpu() else "lane")
     bm_dtype: str = "float32"      # 'float32' | 'bfloat16' branch metrics
     renorm_every: int = 1          # path-metric renormalization period
     block_frames: int | str = 1    # intra-frame blocks per frame, or 'auto'

@@ -19,19 +19,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:                                       # jax >= 0.6 re-export
-    from jax import shard_map
-except ImportError:                        # jax 0.4.x / 0.5.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-        # old API spells the arg check_rep; translate and drop unknowns
-        if check_vma is not None:
-            kw.setdefault("check_rep", check_vma)
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
 
 __all__ = ["init_ef", "compressed_grads", "make_compressed_train_step"]
 

@@ -25,10 +25,10 @@ from __future__ import annotations
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core.pipeline import DecoderConfig, make_frame_decoder
-from .compress import shard_map
 
 __all__ = ["frame_mesh", "make_sharded_frame_decoder"]
 

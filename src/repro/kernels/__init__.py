@@ -1,5 +1,7 @@
-"""Pallas TPU kernels for the paper's compute hot-spot (validated with
-interpret=True on CPU; see EXAMPLE.md for the layout convention).
+"""Pallas TPU kernels for the paper's compute hot-spot: compiled by Mosaic
+on a TPU (sublane layout), interpreted elsewhere, and checked bit-exact
+against ``ref`` in interpret mode; ``packing.Layout`` describes the two
+layouts.
 
 Submodules (``ops``, ``ref``, ``autotune``, ``packing``, ...) are imported
 on first use rather than eagerly: ``core.traceback`` consumes the layout

@@ -43,10 +43,10 @@ from .block import resolve_block
 from .packing import Layout, packed_width
 from .tunedb import TUNE_DB, TuneDB, platform_id
 
-__all__ = ["TilePlan", "DecodePlan", "mosaic_padded_bytes",
+__all__ = ["TilePlan", "DecodePlan", "mosaic_padded_bytes", "tile_ok",
            "unified_vmem_bytes", "split_vmem_bytes", "plan_tiles",
            "plan_decode", "measure_plan", "DEFAULT_VMEM_BUDGET",
-           "CANDIDATE_TILES", "MAX_FRAMES_PER_TILE"]
+           "CANDIDATE_TILES", "MAX_FRAMES_PER_TILE", "LANES", "lane_chunks"]
 # (subframe-geometry validation lives on FrameSpec.validate itself)
 
 DEFAULT_VMEM_BUDGET = 2 * 1024 * 1024          # bytes, per grid step
@@ -59,6 +59,10 @@ CANDIDATE_TILES = tuple(8 << i for i in
                         range((MAX_FRAMES_PER_TILE // 8).bit_length()))
 
 _BM_ITEMSIZE = {"float32": 4, "bfloat16": 2}
+
+#: TPU lane width. The sublane layout puts frames on the lanes, so its
+#: compiled frame tile is a multiple of this — or the whole batch.
+LANES = 128
 
 
 def _rup(n: int, m: int) -> int:
@@ -127,14 +131,14 @@ def _shapes_unified(trellis: Trellis, spec: FrameSpec, FT: int,
     f0, v2s = _geometry(spec)
     nsub = spec.f // f0
     if layout is Layout.SUBLANE:
-        sel = (L * W, FT) if pack else (L, S, FT)
+        C, lanes = lane_chunks(FT)
         return (
-            ("llr_block", (FT, L * beta), 4),
-            ("bm_compressed", (L * half, FT), bm_isz),
-            ("sel_survivors", sel, 4),
-            ("amax", (L, FT), 4),
-            ("tb_bits", (f0 + v2s, nsub, FT), 4),
-            ("out_block", (FT, spec.f), 4),
+            ("llr_block", (C, L * beta, lanes), 4),
+            ("bm_compressed", (C, half * L, lanes), bm_isz),
+            ("sel_survivors", (C, L * (W if pack else S), lanes), 4),
+            ("amax", (C, L, lanes), 4),
+            ("path_metrics", (C, S, lanes), 4),
+            ("out_block", (C, spec.f, lanes), 4),
         )
     sel_w = W if pack else S
     return (
@@ -161,8 +165,8 @@ def _shapes_split(trellis: Trellis, spec: FrameSpec, FT: int,
     if layout is Layout.SUBLANE:
         sel = ((L * W, FT), 4) if pack else ((L, S, FT), 1)
         return (
-            ("llr_block", (FT, L * beta), 4),
-            ("bm_compressed", (L * half, FT), bm_isz),
+            ("llr_block", (L * beta, FT), 4),
+            ("bm_compressed", (half * L, FT), bm_isz),
             ("sel_stream", *sel),
             ("amax_stream", (FT, L), 4),
         )
@@ -173,6 +177,26 @@ def _shapes_split(trellis: Trellis, spec: FrameSpec, FT: int,
         ("sel_stream", *sel),
         ("amax_stream", (FT, L), 4),
     )
+
+
+def lane_chunks(frames_per_tile: int) -> tuple:
+    """(C, lanes): how the sublane kernels lay a frame tile on the lanes —
+    C chunks of 128 frames when the tile is a multiple of 128 (Mosaic's
+    strided loads need a 128-lane base array), else one chunk of the
+    whole tile."""
+    if frames_per_tile % LANES == 0:
+        return frames_per_tile // LANES, LANES
+    return 1, frames_per_tile
+
+
+def tile_ok(layout, frames_per_tile: int, max_frames: int | None) -> bool:
+    """Whether the chip's tiling accepts this frame tile: the sublane
+    layout's frame axis is the lane axis of every block, so a tile fills
+    whole 128-lane rows, or covers the whole (padded) batch in one grid
+    step. The lane layout runs in interpret mode only and takes any."""
+    return (Layout(layout) is not Layout.SUBLANE
+            or frames_per_tile % LANES == 0
+            or (max_frames is not None and frames_per_tile >= max_frames))
 
 
 def _footprint(shapes, mosaic: bool):
@@ -253,6 +277,7 @@ def plan_tiles(trellis: Trellis, spec: FrameSpec, *,
         cap = next((c for c in candidates if c >= max_frames),
                    candidates[-1])
         candidates = [c for c in candidates if c <= cap]
+    candidates = [c for c in candidates if tile_ok(layout, c, max_frames)]
 
     best = None
     for ft in candidates:
@@ -409,7 +434,8 @@ def _measure_candidates(trellis: Trellis, plan_spec: FrameSpec,
                     mosaic=True))
     ft0 = analytic.tile.frames_per_tile
     for ft in (ft0 // 2, ft0 * 2):
-        if CANDIDATE_TILES[0] <= ft <= MAX_FRAMES_PER_TILE:
+        if (CANDIDATE_TILES[0] <= ft <= MAX_FRAMES_PER_TILE
+                and tile_ok(analytic.tile.layout, ft, eff_max)):
             tiles.append(_tile_at(
                 trellis, plan_spec, ft, unified=unified,
                 pack_survivors=pack_survivors, radix=radix,

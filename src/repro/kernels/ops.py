@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.framed import FrameSpec, merge_blocks, reframe_blocks
+from ..core.pipeline import on_tpu
 from ..core.traceback import parallel_traceback_frames, serial_traceback_frames
 from ..core.trellis import Trellis
 from ..obs.tracer import get_tracer
@@ -46,9 +47,10 @@ def viterbi_decode_frames(frames: jax.Array, trellis: Trellis,
                           spec: FrameSpec, *, unified: bool = True,
                           frames_per_tile: int | str = "auto",
                           pack_survivors: bool = True, radix: int = 4,
-                          layout: str = "lane", bm_dtype: str = "float32",
+                          layout: str | None = None,
+                          bm_dtype: str = "float32",
                           block_frames: int = 1, overlap: int = 0,
-                          interpret: bool = True) -> jax.Array:
+                          interpret: bool | None = None) -> jax.Array:
     """(F, L, beta) LLR frames -> (F, f) decoded bits.
 
     unified=True  : the paper's single-kernel path (survivors in VMEM only).
@@ -61,9 +63,10 @@ def viterbi_decode_frames(frames: jax.Array, trellis: Trellis,
                     unified kernel, the HBM stream for the split baseline).
     radix         : 2, or 4 to fuse two trellis stages per ACS/traceback
                     step.
-    layout        : 'lane' (frames on sublanes, PR-1 orientation) or
-                    'sublane' (Mosaic-native, frames on lanes; the layout
-                    whose packing survives hardware lane padding).
+    layout        : 'lane' (frames on sublanes, original orientation,
+                    interpret mode only) or 'sublane' (frames on lanes,
+                    the layout Mosaic compiles). None takes the
+                    platform's: 'sublane' on a TPU, else 'lane'.
     bm_dtype      : 'float32' | 'bfloat16' branch-metric storage. All knob
                     combinations decode bit-identically except bf16, which
                     quantizes the metrics once (BER-neutral to ~1e-3).
@@ -78,7 +81,16 @@ def viterbi_decode_frames(frames: jax.Array, trellis: Trellis,
                     bit-identical when overlap >= block.full_overlap().
     overlap       : per-block training/truncation region (stages); only
                     meaningful with block_frames > 1.
+    interpret     : run the Pallas kernels in the interpreter. None takes
+                    the platform's: compiled on a TPU, interpreted
+                    elsewhere. A compiled request for a knob Mosaic cannot
+                    run (lane layout, bf16 metrics, split kernel) raises
+                    ValueError instead of falling back to the interpreter.
     """
+    if interpret is None:
+        interpret = not on_tpu()
+    if layout is None:
+        layout = "sublane" if on_tpu() else "lane"
     spec.validate()
     # entry validation (trace-time, so invalid calls fail with a clear
     # message instead of a shape error deep inside a kernel)

@@ -30,9 +30,10 @@ padding costs at most 8/W and fills the lanes with frames, which is what
 makes the compression survive compiled mode (kernels/autotune.py's
 ``mosaic_padded_bytes`` models exactly this).
 
-All functions are pure jnp on static shapes, so they work identically
-inside Pallas kernel bodies (interpret or compiled — XLA folds the shift
-table) and at the JAX level (packing the split kernel's HBM stream).
+All functions are pure jnp on static shapes. ``pack_rows`` is the form
+the compiled kernel packs with (no reshape); the others serve interpret-
+mode kernel bodies and the JAX level (packing the split kernel's HBM
+stream, the JAX tracebacks).
 Codes with S < 32 states (e.g. K=5, K=4 test codes) pack into one
 zero-padded word — still a win vs S int8s for S > 4.
 """
@@ -40,10 +41,11 @@ from __future__ import annotations
 
 import enum
 
+import jax
 import jax.numpy as jnp
 
-__all__ = ["BITS", "Layout", "packed_width", "pack_bits", "unpack_bits",
-           "extract_bit"]
+__all__ = ["BITS", "Layout", "packed_width", "pack_bits", "pack_rows",
+           "unpack_bits", "extract_bit"]
 
 BITS = 32          # word width: int32 is the TPU-native integer lane type
 
@@ -96,6 +98,25 @@ def pack_bits(sel: jnp.ndarray, layout: Layout = Layout.LANE) -> jnp.ndarray:
     weights = jnp.left_shift(jnp.int32(1),
                              jnp.arange(BITS, dtype=jnp.int32))[:, None]
     return jnp.sum(x * weights, axis=-2, dtype=jnp.int32)
+
+
+def pack_rows(sel: jnp.ndarray) -> list:
+    """(n, N) {0,1}-valued -> ``packed_width(n)`` int32 rows of (1, N).
+
+    The SUBLANE packing of one stage inside a compiled kernel: row ``s``
+    lands in word ``s // 32`` at bit ``s % 32``. Built from static
+    32-row slices and a 2-D iota shift — no reshape, which Mosaic cannot
+    lower across the sublane axis. The rows are returned separately so
+    the caller stores each at its own (unaligned) row offset."""
+    x = sel.astype(jnp.int32)
+    n = x.shape[0]
+    words = []
+    for w in range(packed_width(n)):
+        blk = x[w * BITS:min(n, (w + 1) * BITS)]
+        shift = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 0)
+        words.append(jnp.sum(blk << shift, axis=0, keepdims=True,
+                             dtype=jnp.int32))
+    return words
 
 
 def unpack_bits(packed: jnp.ndarray, n: int,

@@ -23,47 +23,57 @@ tiles (kernels/packing.Layout):
     (L, S, F) int8. The JAX-level traceback consumes this orientation
     directly (core/traceback.*_frames), so the stream is never transposed.
 ``bm_dtype`` sets the branch-metric scratch dtype (see acs.py).
+
+This baseline runs in interpret mode only; on the chip the unified kernel
+is the decode path, and a compiled request here raises.
 """
 from __future__ import annotations
 
 import functools
 
-import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.trellis import Trellis
-from .acs import acs_scan
-from .packing import Layout, pack_bits, packed_width
+from .acs import acs_scan_lane, acs_scan_sublane
+from .packing import Layout, pack_bits, pack_rows, packed_width
 
 __all__ = ["forward_frames"]
 
 
-def _kernel(llr_ref, sel_ref, amax_ref, bm_ref, *, trellis: Trellis, L: int,
-            pack: bool, radix: int, layout: Layout, bm_dtype):
+def _kernel(llr_ref, sel_ref, amax_ref, bm_ref, *scratch, trellis: Trellis,
+            L: int, pack: bool, radix: int, layout: Layout, bm_dtype):
     # same forward recursion as the unified kernel (shared via acs.py);
     # only the survivor destination differs: HBM-backed output refs.
-    sub = layout is Layout.SUBLANE
-    W = packed_width(trellis.num_states)
+    if layout is Layout.SUBLANE:
+        sigma_ref, = scratch
+        W = packed_width(trellis.num_states)
 
-    def store(t, sel, sigma):
-        if sub:                                      # sel/sigma are (S, FT)
+        def store(t, c, sel):                        # (S/2, FT) halves
+            full = jnp.concatenate([s.astype(jnp.int32) for s in sel], axis=0)
             if pack:
-                sel_ref[pl.ds(t * W, W)] = pack_bits(sel, Layout.SUBLANE)
+                for w, word in enumerate(pack_rows(full)):
+                    sel_ref[pl.ds(t * W + w, 1), :] = word
             else:
-                sel_ref[t] = sel.astype(jnp.int8)
-            amax_ref[:, t] = jnp.argmax(sigma, axis=0).astype(jnp.int32)
-        else:                                        # sel/sigma are (FT, S)
-            if pack:
-                sel_ref[:, t, :] = pack_bits(sel)    # -> HBM, 1 bit/state
-            else:
-                sel_ref[:, t, :] = sel.astype(jnp.int8)  # 1 byte/state
-            amax_ref[:, t] = jnp.argmax(sigma, axis=1).astype(jnp.int32)
+                sel_ref[t] = full.astype(jnp.int8)
+            amax_ref[:, t] = jnp.argmax(sigma_ref[c], axis=0).astype(
+                jnp.int32)
 
-    acs_scan(llr_ref, bm_ref, trellis=trellis, L=L, radix=radix, store=store,
-             layout=layout, bm_dtype=bm_dtype)
+        acs_scan_sublane(llr_ref, bm_ref, sigma_ref, trellis=trellis, L=L,
+                         radix=radix, store=store, bm_dtype=bm_dtype)
+        return
+
+    def store(t, sel, sigma):                        # sel/sigma are (FT, S)
+        if pack:
+            sel_ref[:, t, :] = pack_bits(sel)        # -> HBM, 1 bit/state
+        else:
+            sel_ref[:, t, :] = sel.astype(jnp.int8)  # 1 byte/state
+        amax_ref[:, t] = jnp.argmax(sigma, axis=1).astype(jnp.int32)
+
+    acs_scan_lane(llr_ref, bm_ref, trellis=trellis, L=L, radix=radix,
+                  store=store, bm_dtype=bm_dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -77,7 +87,14 @@ def forward_frames(frames: jax.Array, *, trellis: Trellis,
 
     sel layout/shape: lane (F, L, S) int8 or packed (F, L, ceil(S/32))
     int32; sublane (L, S, F) int8 or packed (L*ceil(S/32), F) int32.
+    Interpret mode only: a compiled request raises ``ValueError``.
     """
+    if not interpret:
+        raise ValueError(
+            "the split kernel (backend='kernel_split') runs only in "
+            "interpret mode: its per-stage column stores into the HBM "
+            "argmax stream do not lower on Mosaic; use the unified kernel "
+            "(backend='kernel') with interpret=False")
     F, L, beta = frames.shape
     FT = frames_per_tile
     assert F % FT == 0, (F, FT)
@@ -90,15 +107,18 @@ def forward_frames(frames: jax.Array, *, trellis: Trellis,
     sub = layout is Layout.SUBLANE
 
     if sub:
-        inputs = frames.reshape(F, L * beta)
-        in_spec = pl.BlockSpec((FT, L * beta), lambda i: (i, 0))
+        # one lane chunk of the whole tile (see acs.acs_scan_sublane)
+        inputs = (frames.astype(jnp.float32).reshape(F // FT, FT, L * beta)
+                  .transpose(0, 2, 1))
+        in_spec = pl.BlockSpec((1, L * beta, FT), lambda i: (i, 0, 0))
         if pack_survivors:
             sel_spec = pl.BlockSpec((L * W, FT), lambda i: (0, i))
             sel_shape = jax.ShapeDtypeStruct((L * W, F), jnp.int32)
         else:
             sel_spec = pl.BlockSpec((L, S, FT), lambda i: (0, 0, i))
             sel_shape = jax.ShapeDtypeStruct((L, S, F), jnp.int8)
-        bm_scratch = pltpu.VMEM((L * half, FT), bm_dt)
+        scratch = [pltpu.VMEM((1, half * L, FT), bm_dt),
+                   pltpu.VMEM((1, S, FT), jnp.float32)]
     else:
         inputs = frames
         in_spec = pl.BlockSpec((FT, L, beta), lambda i: (i, 0, 0))
@@ -106,7 +126,7 @@ def forward_frames(frames: jax.Array, *, trellis: Trellis,
         sel_dt = jnp.int32 if pack_survivors else jnp.int8
         sel_spec = pl.BlockSpec((FT, L, sel_w), lambda i: (i, 0, 0))
         sel_shape = jax.ShapeDtypeStruct((F, L, sel_w), sel_dt)
-        bm_scratch = pltpu.VMEM((L, FT, half), bm_dt)
+        scratch = [pltpu.VMEM((L, FT, half), bm_dt)]
 
     kern = functools.partial(_kernel, trellis=trellis, L=L,
                              pack=pack_survivors, radix=radix, layout=layout,
@@ -117,6 +137,6 @@ def forward_frames(frames: jax.Array, *, trellis: Trellis,
         in_specs=[in_spec],
         out_specs=[sel_spec, pl.BlockSpec((FT, L), lambda i: (i, 0))],
         out_shape=[sel_shape, jax.ShapeDtypeStruct((F, L), jnp.int32)],
-        scratch_shapes=[bm_scratch],
+        scratch_shapes=scratch,
         interpret=interpret,
     )(inputs)

@@ -232,3 +232,43 @@ def test_kernel_frame_padding(rng):
     want = np.asarray(ref.unified_decode_frames_ref(frames, STD_K7, spec))
     got = np.asarray(ops.viterbi_decode_frames(frames, STD_K7, spec))
     assert got.shape == want.shape and np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("knobs,match", [
+    (dict(layout="lane"), "layout='lane' runs only in interpret mode"),
+    (dict(layout="sublane", bm_dtype="bfloat16"),
+     "bm_dtype='bfloat16' runs only in interpret mode"),
+    (dict(layout="sublane", unified=False), "split kernel"),
+    (dict(layout="sublane", frames_per_tile=8), "Mosaic cannot tile it"),
+])
+def test_compiled_request_refuses_what_mosaic_cannot_run(rng, knobs, match):
+    """A compiled (interpret=False) call for a knob outside the compiled
+    path raises with the reason — it is never silently interpreted. The
+    checks run at trace time, before anything is lowered."""
+    spec = FrameSpec(f=64, v1=16, v2=16, f0=16, v2s=16)
+    frames = jnp.zeros((16, spec.frame_len, 2), jnp.float32)
+    with pytest.raises(ValueError, match=match):
+        ops.viterbi_decode_frames(frames, STD_K7, spec, interpret=False,
+                                  **knobs)
+
+
+def test_platform_defaults_off_tpu():
+    """Off a TPU the kernel entry point keeps interpret mode and the lane
+    layout; DecoderConfig keeps the reference backend (the chip's
+    defaults — kernel, compiled, sublane — are exercised by
+    chip_smoke.py)."""
+    from repro.core import DecoderConfig
+    from repro.obs import Tracer, set_tracer
+    cfg = DecoderConfig()
+    assert (cfg.backend, cfg.interpret, cfg.layout) == \
+        ("reference", True, "lane")
+    spec = FrameSpec(f=16, v1=8, v2=8)
+    t = Tracer()
+    set_tracer(t)
+    try:
+        ops.viterbi_decode_frames(jnp.zeros((8, spec.frame_len, 2)),
+                                  STD_K7, spec)
+    finally:
+        set_tracer(None)
+    ev, = [r.attrs for r in t.spans() if r.name == "kernel_trace"]
+    assert ev["interpret"] is True and ev["layout"] == "lane"
