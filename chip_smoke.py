@@ -11,7 +11,9 @@ One process; no child touches JAX. The data is made on the device from
   A  single shot: ``make_decoder(DecoderConfig(spec=...))`` on 2^21 bits
      of the K=7 (171,133) code; bit-identical to the reference backend
      on the same LLRs (run on the host CPU), BER <= 1e-3 against the sent
-     bits.
+     bits. The reference backend run on the chip, through ``make_decoder``
+     and through the serve layer's failover batch program, must give the
+     same bits.
   B  serve: one ``DecodeServer(slots=16)``, 256 sessions in the
      ``benchmarks/throughput.py`` serve mix (half K=7 rate 1/2, a quarter
      K=7 rate 3/4 pushing raw punctured symbols, a quarter K=5 rate 1/2),
@@ -55,6 +57,7 @@ from repro.channel.sim import awgn, bpsk                 # noqa: E402
 from repro.compile_cache import use_compile_cache       # noqa: E402
 from repro.core import (DecoderConfig, FrameSpec, STD_K7,  # noqa: E402
                         encode, make_decoder, make_trellis, puncture)
+from repro.core.framed import frame_llr                  # noqa: E402
 from repro.obs import Tracer, set_tracer                 # noqa: E402
 from repro.serve import DecodeServer, PlanCache          # noqa: E402
 
@@ -95,8 +98,8 @@ def kernel_cfg(over: dict, **kw) -> DecoderConfig:
 def reference(cfg: DecoderConfig, stream, n: int) -> np.ndarray:
     """The reference backend's decode of ``stream`` (or of a batch of
     streams, leading axis), run on the host CPU: independent of the chip's
-    compiler, which miscompiles the reference program at 2^21 bits
-    (see PERF.md)."""
+    compiler, which has miscompiled the reference program before (see
+    PERF.md)."""
     host = jax.devices("cpu")[0]
     dec = make_decoder(dataclasses.replace(cfg, backend="reference"))
     with jax.default_device(host):
@@ -136,6 +139,8 @@ class Traced:
     def kernel_plans(self) -> list:
         evs = [r.attrs for r in self.tracer.spans()
                if r.name == "kernel_trace"]
+        check(evs, "no kernel_trace event: the kernel did not compile in "
+                   "this phase")
         bad = [e for e in evs if e["interpret"] is not self.interpret]
         check(not bad, f"kernel traced with interpret != "
                        f"{self.interpret}: {bad}")
@@ -186,8 +191,24 @@ def phase_a(key, *, frames: int = 8192, over: dict | None = None,
           f"phase A: kernel != reference on {int((out != want).sum())} "
           f"bits (BER kernel {ber}, reference {np.mean(want != bits)})")
     check(ber <= max_ber, f"phase A: BER {ber} > {max_ber}")
+    # the reference backend on this device too: the serve layer degrades
+    # and fails over to it, so it must be exact here, not only on the host
+    ref_cfg = dataclasses.replace(cfg, backend="reference")
+    frames_in = jax.jit(lambda x: frame_llr(x, SPEC_A))(llr)
+    on_device = {
+        "make_decoder": make_decoder(ref_cfg)(llr, n),
+        "serve failover batch": PlanCache().batch_decoder(
+            ref_cfg, frames)(frames_in).reshape(-1)[:n]}
+    for name, got in on_device.items():
+        got = np.asarray(got)
+        check(np.array_equal(got, want),
+              f"phase A: reference backend ({name}) on "
+              f"{jax.devices()[0].platform} != the host-CPU reference on "
+              f"{int((got != want).sum())} bits")
     log_plans("A", plans)
-    log(f"phase A: {n} bits, BER {ber:.3e}, bit-identical to reference; "
+    log(f"phase A: {n} bits, BER {ber:.3e}, bit-identical to the host-CPU "
+        f"reference, as are the reference backend's make_decoder and serve "
+        f"failover programs on this device; "
         f"smoke figure, not a benchmark: first call (compile + run) "
         f"{t_first:.3f} s, steady call {t_steady:.4f} s")
     return {"bits": n, "ber": ber, "first_s": t_first, "steady_s": t_steady}

@@ -20,7 +20,7 @@ from .traceback import parallel_traceback, serial_traceback
 from .trellis import Trellis
 
 __all__ = ["FrameSpec", "frame_llr", "decode_frame", "framed_decode",
-           "reframe_blocks", "merge_blocks"]
+           "reframe_blocks", "merge_blocks", "flatten_bits"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -151,9 +151,21 @@ def reframe_blocks(frames: jax.Array, spec: FrameSpec, block_frames: int,
 def merge_blocks(bits: jax.Array, block_frames: int) -> jax.Array:
     """(F*B, fb) per-block kept bits -> (F, f) frame bits. The trailing
     overlap was already truncated by the per-block decode (a block keeps
-    only its fb body stages), so the merge is a pure reshape."""
+    only its fb body stages), so the merge is a pure reshape, behind the
+    same barrier as ``flatten_bits``."""
     FB, fb = bits.shape
-    return bits.reshape(FB // int(block_frames), int(block_frames) * fb)
+    return jax.lax.optimization_barrier(bits).reshape(
+        FB // int(block_frames), int(block_frames) * fb)
+
+
+def flatten_bits(bits: jax.Array) -> jax.Array:
+    """(F, f) decoded frames -> (F*f,) stream order, for use inside a jit.
+
+    The (F, f) bits are materialized before the reshape: fused into the
+    reference decode's traceback, the TPU compiler flattens 8192 and
+    16384 f=256 frames wrongly, every frame from bit 16 on (PERF.md;
+    ``scripts/chip_witness.py`` reproduces it)."""
+    return jax.lax.optimization_barrier(bits).reshape(-1)
 
 
 @partial(jax.jit, static_argnums=(1, 2, 3))
@@ -164,4 +176,4 @@ def framed_decode(llr: jax.Array, trellis: Trellis, spec: FrameSpec,
     n = llr.shape[0] if n_out is None else n_out
     frames = frame_llr(llr, spec)                     # (F, L, beta)
     bits = jax.vmap(lambda fr: decode_frame(fr, trellis, spec))(frames)
-    return bits.reshape(-1)[:n]
+    return flatten_bits(bits)[:n]

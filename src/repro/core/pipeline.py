@@ -15,18 +15,28 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from .framed import FrameSpec, framed_decode, frame_llr, decode_frame
+from .framed import FrameSpec, decode_frame, flatten_bits, frame_llr
 from .puncture import depuncture, check_alignment
 from .sanitize import LLR_CLIP as _LLR_CLIP
 from .trellis import Trellis, STD_K7
 
-__all__ = ["DecoderConfig", "make_decoder", "make_frame_decoder", "on_tpu"]
+__all__ = ["DecoderConfig", "make_decoder", "make_frame_decoder",
+           "platform_defaults"]
 
 
-def on_tpu() -> bool:
-    """Whether the platform defaults (DecoderConfig, ops) pick the
-    compiled kernel: true exactly when JAX's default backend is a TPU."""
-    return jax.default_backend() == "tpu"
+def platform_defaults() -> dict:
+    """``backend``, ``interpret`` and ``layout`` where the caller leaves
+    them (DecoderConfig, ops.viterbi_decode_frames), from
+    ``jax.default_backend()``: on a TPU the compiled unified kernel in the
+    sublane layout; elsewhere the pure-JAX reference, with the Pallas
+    kernels, when asked for, interpreted in the lane layout."""
+    if jax.default_backend() == "tpu":
+        return {"backend": "kernel", "interpret": False, "layout": "sublane"}
+    return {"backend": "reference", "interpret": True, "layout": "lane"}
+
+
+def _platform(knob: str):
+    return dataclasses.field(default_factory=lambda: platform_defaults()[knob])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -41,11 +51,7 @@ class DecoderConfig:
     kernel behavior).
 
     ``backend``, ``interpret`` and ``layout`` default from the platform
-    (``jax.default_backend()`` when the config is built): on a TPU the
-    compiled unified kernel in the sublane layout (``'kernel'``, False,
-    ``'sublane'``); elsewhere the pure-JAX reference with the Pallas
-    kernels, when asked for, interpreted in the lane layout
-    (``'reference'``, True, ``'lane'``). ``layout`` picks the
+    when the config is built (``platform_defaults``). ``layout`` picks the
     survivor-memory orientation ('lane' = frames on sublanes, interpret
     mode only; 'sublane' = frames on lanes, the layout Mosaic compiles) —
     still bit-exact. ``bm_dtype='bfloat16'`` stores branch metrics
@@ -73,17 +79,12 @@ class DecoderConfig:
     trellis: Trellis = STD_K7
     spec: FrameSpec = FrameSpec()
     rate: str = "1/2"
-    # 'reference' | 'kernel' | 'kernel_split'
-    backend: str = dataclasses.field(
-        default_factory=lambda: "kernel" if on_tpu() else "reference")
-    # Pallas interpret mode: off only where the kernels compile
-    interpret: bool = dataclasses.field(default_factory=lambda: not on_tpu())
+    backend: str = _platform("backend")   # 'reference'|'kernel'|'kernel_split'
+    interpret: bool = _platform("interpret")   # Pallas interpret mode
     pack_survivors: bool = True    # bit-pack survivors 32x (kernel backends)
     radix: int = 4                 # 2 | 4 trellis stages per ACS step
     frames_per_tile: int | str = "auto"   # tile size, or VMEM-planned
-    # 'lane' | 'sublane' survivor layout
-    layout: str = dataclasses.field(
-        default_factory=lambda: "sublane" if on_tpu() else "lane")
+    layout: str = _platform("layout")     # 'lane' | 'sublane' survivors
     bm_dtype: str = "float32"      # 'float32' | 'bfloat16' branch metrics
     renorm_every: int = 1          # path-metric renormalization period
     block_frames: int | str = 1    # intra-frame blocks per frame, or 'auto'
@@ -198,6 +199,6 @@ def make_decoder(cfg: DecoderConfig):
             llr = stream if stream.ndim == 2 else stream.reshape(n, -1)
         frames = frame_llr(llr, cfg.spec)             # (F, L, beta)
         bits = _decode_frames(frames)                 # (F, f)
-        return bits.reshape(-1)[:n]
+        return flatten_bits(bits)[:n]
 
     return decode

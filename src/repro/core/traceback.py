@@ -15,19 +15,14 @@ The parallel version is a *vectorized pointer chase*: all ``nsub`` cursors
 advance together, so the backward pass costs f0+v2s vector steps instead of
 f+v2 serial steps — the D/D' parallelism of Table I row (c).
 
-The ``*_frames`` variants consume a whole batch of frames in one of the
-two survivor-stream layouts the split kernel emits (kernels/packing.Layout):
-frame-major ``lane`` streams are vmapped over frames, while Mosaic-native
-``sublane`` streams (frames on the trailing lane axis) are chased directly
-with the frame axis vectorized — the stream is never transposed on its way
-from HBM to the decoded bits.
+The ``*_frames`` variants trace back a whole batch of frames from the
+frame-major survivor stream the split kernel emits, vmapped over frames.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from ..kernels.packing import Layout, extract_bit, packed_width
 from .trellis import Trellis
 
 __all__ = ["serial_traceback", "parallel_traceback",
@@ -117,90 +112,27 @@ def parallel_traceback(sel: jax.Array, amax: jax.Array, trellis: Trellis,
     return kept.T.reshape((f,))                       # subframes concatenated
 
 
-def _sel_stages(sel: jax.Array, trellis: Trellis, packed: bool) -> jax.Array:
-    """Sublane stream -> (L, W|S, F) stage-major view (packed rows are
-    stored flat as (L*W, F), matching the kernels' scratch layout)."""
-    if packed:
-        W = packed_width(trellis.num_states)
-        return sel.reshape(-1, W, sel.shape[-1])
-    return sel
-
-
 def serial_traceback_frames(sel: jax.Array, amax: jax.Array,
                             trellis: Trellis, v1: int, f: int,
-                            packed: bool = False,
-                            layout: Layout = Layout.LANE) -> jax.Array:
+                            packed: bool = False) -> jax.Array:
     """Serial traceback of a frame batch -> (F, f) bits.
 
-    sel: lane (F, L, S|W); sublane (L*W, F) packed / (L, S, F) unpacked.
-    amax: (F, L) — the chase starts from each frame's last-stage argmax.
+    sel: (F, L, S|W); amax: (F, L) — each frame's chase starts from its
+    last-stage argmax.
     """
-    if Layout(layout) is Layout.LANE:
-        tb = lambda s, a: serial_traceback(s, trellis, a[-1], v1, f,
-                                           packed=packed)
-        return jax.vmap(tb)(sel, amax)
-    sel3 = _sel_stages(sel.astype(jnp.int32), trellis, packed)  # (L, ., F)
-    F = sel3.shape[-1]
-    kshift = trellis.k - 2
-    S = trellis.num_states
-    states0 = amax[:, -1].astype(jnp.int32)           # (F,)
-
-    def step(states, rows):                           # rows (W|S, F)
-        bits = states >> kshift
-        if packed:
-            p = extract_bit(rows, states, Layout.SUBLANE)
-        else:
-            p = rows[states, jnp.arange(F)]
-        return ((states << 1) & (S - 1)) | p, bits    # butterfly arithmetic
-
-    _, bits = jax.lax.scan(step, states0, sel3, reverse=True)  # (L, F)
-    return jax.lax.dynamic_slice(bits, (v1, 0), (f, F)).T
+    tb = lambda s, a: serial_traceback(s, trellis, a[-1], v1, f,
+                                       packed=packed)
+    return jax.vmap(tb)(sel, amax)
 
 
 def parallel_traceback_frames(sel: jax.Array, amax: jax.Array,
                               trellis: Trellis, v1: int, f: int, f0: int,
                               v2s: int, start: str = "boundary",
-                              packed: bool = False,
-                              layout: Layout = Layout.LANE) -> jax.Array:
+                              packed: bool = False) -> jax.Array:
     """Parallel traceback of a frame batch -> (F, f) bits.
 
-    sel: lane (F, L, S|W); sublane (L*W, F) packed / (L, S, F) unpacked.
-    amax: (F, L). In the sublane layout all nsub cursors of all F frames
-    advance in lock-step with frames on the trailing (lane) axis — the
-    JAX-level mirror of the unified kernel's phase 3.
+    sel: (F, L, S|W); amax: (F, L).
     """
-    if Layout(layout) is Layout.LANE:
-        tb = lambda s, a: parallel_traceback(s, a, trellis, v1, f, f0, v2s,
-                                             start, packed=packed)
-        return jax.vmap(tb)(sel, amax)
-    assert f % f0 == 0, "f must be a multiple of f0 (paper §IV-E alignment)"
-    nsub = f // f0
-    sel3 = _sel_stages(sel.astype(jnp.int32), trellis, packed)  # (L, ., F)
-    F = sel3.shape[-1]
-    kshift = trellis.k - 2
-    S = trellis.num_states
-
-    q = jnp.arange(nsub, dtype=jnp.int32)
-    e = v1 + (q + 1) * f0 - 1 + v2s                   # (nsub,)
-    if start == "boundary":
-        states = jnp.take(amax, e, axis=1).T.astype(jnp.int32)  # (nsub, F)
-    elif start == "fixed":
-        states = jnp.zeros((nsub, F), jnp.int32)
-    else:
-        raise ValueError(start)
-
-    def step(states, r):
-        rows = jnp.take(sel3, e - r, axis=0)          # (nsub, W|S, F)
-        bits = states >> kshift
-        if packed:
-            p = extract_bit(rows, states, Layout.SUBLANE)
-        else:
-            onehot = (states[:, None, :]
-                      == jnp.arange(S, dtype=jnp.int32)[None, :, None])
-            p = jnp.sum(rows * onehot.astype(jnp.int32), axis=1)
-        return ((states << 1) & (S - 1)) | p, bits
-
-    _, bits = jax.lax.scan(step, states,
-                           jnp.arange(f0 + v2s, dtype=jnp.int32))
-    kept = bits[v2s:][::-1]                           # (f0, nsub, F) ascending
-    return jnp.transpose(kept, (2, 1, 0)).reshape(F, f)
+    tb = lambda s, a: parallel_traceback(
+        s, a, trellis, v1, f, f0, v2s, start, packed=packed)
+    return jax.vmap(tb)(sel, amax)

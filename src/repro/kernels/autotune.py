@@ -156,27 +156,25 @@ def _shapes_split(trellis: Trellis, spec: FrameSpec, FT: int,
     """((name, shape, itemsize), ...) mirroring viterbi_fwd.py: the per-step
     working set is the LLR block, the bm scratch, and the staged sel/amax
     output blocks — no survivor scratch and no traceback arrays (those live
-    in HBM / run as a separate JAX step)."""
+    in HBM / run as a separate JAX step). Lane layout only, as the kernel."""
+    if layout is not Layout.LANE:
+        raise ValueError("the split kernel runs only in the lane layout")
     S = trellis.num_states
     beta = trellis.beta
     half = 1 << (beta - 1)
     L = spec.frame_len
-    W = packed_width(S)
-    if layout is Layout.SUBLANE:
-        sel = ((L * W, FT), 4) if pack else ((L, S, FT), 1)
-        return (
-            ("llr_block", (L * beta, FT), 4),
-            ("bm_compressed", (half * L, FT), bm_isz),
-            ("sel_stream", *sel),
-            ("amax_stream", (FT, L), 4),
-        )
-    sel = ((FT, L, W), 4) if pack else ((FT, L, S), 1)
+    sel = ((FT, L, packed_width(S)), 4) if pack else ((FT, L, S), 1)
     return (
         ("llr_block", (FT, L, beta), 4),
         ("bm_compressed", (L, FT, half), bm_isz),
         ("sel_stream", *sel),
         ("amax_stream", (FT, L), 4),
     )
+
+
+def _layouts(unified: bool) -> tuple:
+    """The layouts ``layout='auto'`` weighs: the split kernel has one."""
+    return (Layout.LANE, Layout.SUBLANE) if unified else (Layout.LANE,)
 
 
 def lane_chunks(frames_per_tile: int) -> tuple:
@@ -425,7 +423,7 @@ def _measure_candidates(trellis: Trellis, plan_spec: FrameSpec,
     kept so ties resolve to the model's choice."""
     tiles = [analytic.tile]
     if layout == "auto":
-        for lay in (Layout.LANE, Layout.SUBLANE):
+        for lay in _layouts(unified):
             if lay is not analytic.tile.layout:
                 tiles.append(plan_tiles(
                     trellis, plan_spec, pack_survivors=pack_survivors,
@@ -514,7 +512,7 @@ def plan_decode(trellis: Trellis, spec: FrameSpec, *, unified: bool = True,
                    else max_frames)
         if frames_per_tile is not None:
             lay, mosaic = _resolve(
-                Layout.SUBLANE if layout == "auto" else layout, None)
+                _layouts(unified)[-1] if layout == "auto" else layout, None)
             model = unified_vmem_bytes if unified else split_vmem_bytes
             total, breakdown = model(
                 trellis, plan_spec, frames_per_tile,
@@ -529,7 +527,7 @@ def plan_decode(trellis: Trellis, spec: FrameSpec, *, unified: bool = True,
                                 radix=radix, vmem_budget=vmem_budget,
                                 max_frames=eff_max, unified=unified,
                                 layout=lay, bm_dtype=bm_dtype, mosaic=True)
-                     for lay in (Layout.LANE, Layout.SUBLANE)]
+                     for lay in _layouts(unified)]
             tile = max(plans, key=lambda p: (p.frames_per_tile, -p.vmem_bytes))
         else:
             tile = plan_tiles(trellis, plan_spec,

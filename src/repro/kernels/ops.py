@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.framed import FrameSpec, merge_blocks, reframe_blocks
-from ..core.pipeline import on_tpu
+from ..core.pipeline import platform_defaults
 from ..core.traceback import parallel_traceback_frames, serial_traceback_frames
 from ..core.trellis import Trellis
 from ..obs.tracer import get_tracer
@@ -55,7 +55,8 @@ def viterbi_decode_frames(frames: jax.Array, trellis: Trellis,
 
     unified=True  : the paper's single-kernel path (survivors in VMEM only).
     unified=False : prior-work baseline — forward kernel streams survivors
-                    to HBM, traceback runs as a separate batched step.
+                    to HBM, traceback runs as a separate batched step
+                    (interpret mode and the lane layout only).
     frames_per_tile: frames decoded per kernel grid step, or 'auto' to let
                     the VMEM-budget planner choose (autotune.plan_tiles,
                     budgeting whichever kernel/layout/dtype runs here).
@@ -88,9 +89,9 @@ def viterbi_decode_frames(frames: jax.Array, trellis: Trellis,
                     ValueError instead of falling back to the interpreter.
     """
     if interpret is None:
-        interpret = not on_tpu()
+        interpret = platform_defaults()["interpret"]
     if layout is None:
-        layout = "sublane" if on_tpu() else "lane"
+        layout = platform_defaults()["layout"]
     spec.validate()
     # entry validation (trace-time, so invalid calls fail with a clear
     # message instead of a shape error deep inside a kernel)
@@ -161,20 +162,14 @@ def viterbi_decode_frames(frames: jax.Array, trellis: Trellis,
                                    pack_survivors=pack_survivors, radix=radix,
                                    layout=lay.value, bm_dtype=bm_dtype,
                                    interpret=interpret)
-        # HBM round-trip; the sublane stream keeps frames on the trailing
-        # axis
-        if lay is Layout.SUBLANE:
-            sel, amax = sel[..., :F], amax[:F]
-        else:
-            sel, amax = sel[:F], amax[:F]
+        sel, amax = sel[:F], amax[:F]                 # HBM round-trip
         if spec.parallel_tb:
             bits = parallel_traceback_frames(
                 sel, amax, trellis, spec.v1, spec.f, spec.f0, spec.v2s,
-                spec.start, packed=pack_survivors, layout=lay)
+                spec.start, packed=pack_survivors)
         else:
             bits = serial_traceback_frames(sel, amax, trellis, spec.v1,
-                                           spec.f, packed=pack_survivors,
-                                           layout=lay)
+                                           spec.f, packed=pack_survivors)
     if block_frames > 1:
         bits = merge_blocks(bits, block_frames)       # (F_in, f)
         assert bits.shape[0] == F_in

@@ -13,19 +13,13 @@ once the unified kernel packs its VMEM scratch. ``radix=4`` fuses two
 trellis stages per scan step (see tables.radix4_tables); both knobs are
 bit-exact vs the radix-2 / unpacked seed kernel.
 
-``layout`` re-orients the stream for the TPU's (8 sublane x 128 lane)
-tiles (kernels/packing.Layout):
-  * lane    — (F, L, W) int32 / (F, L, S) int8: frame-major, packed words
-    (or states) trailing. The per-tile staging block lane-pads the tiny W
-    dim to 128 on real Mosaic.
-  * sublane — frames on the trailing lane axis: packed (L*W, F) int32
-    (stage-flattened rows, like the unified kernel's scratch) or unpacked
-    (L, S, F) int8. The JAX-level traceback consumes this orientation
-    directly (core/traceback.*_frames), so the stream is never transposed.
-``bm_dtype`` sets the branch-metric scratch dtype (see acs.py).
+The stream is frame-major (the lane layout, kernels/packing.Layout):
+(F, L, W) int32 packed words or (F, L, S) int8 selectors. ``bm_dtype``
+sets the branch-metric scratch dtype (see acs.py).
 
-This baseline runs in interpret mode only; on the chip the unified kernel
-is the decode path, and a compiled request here raises.
+This baseline runs in interpret mode and in the lane layout only; on the
+chip the unified kernel is the decode path, and a compiled or sublane
+request here raises.
 """
 from __future__ import annotations
 
@@ -37,34 +31,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.trellis import Trellis
-from .acs import acs_scan_lane, acs_scan_sublane
-from .packing import Layout, pack_bits, pack_rows, packed_width
+from .acs import acs_scan_lane
+from .packing import Layout, pack_bits, packed_width
 
 __all__ = ["forward_frames"]
 
 
-def _kernel(llr_ref, sel_ref, amax_ref, bm_ref, *scratch, trellis: Trellis,
-            L: int, pack: bool, radix: int, layout: Layout, bm_dtype):
+def _kernel(llr_ref, sel_ref, amax_ref, bm_ref, *, trellis: Trellis, L: int,
+            pack: bool, radix: int, bm_dtype):
     # same forward recursion as the unified kernel (shared via acs.py);
     # only the survivor destination differs: HBM-backed output refs.
-    if layout is Layout.SUBLANE:
-        sigma_ref, = scratch
-        W = packed_width(trellis.num_states)
-
-        def store(t, c, sel):                        # (S/2, FT) halves
-            full = jnp.concatenate([s.astype(jnp.int32) for s in sel], axis=0)
-            if pack:
-                for w, word in enumerate(pack_rows(full)):
-                    sel_ref[pl.ds(t * W + w, 1), :] = word
-            else:
-                sel_ref[t] = full.astype(jnp.int8)
-            amax_ref[:, t] = jnp.argmax(sigma_ref[c], axis=0).astype(
-                jnp.int32)
-
-        acs_scan_sublane(llr_ref, bm_ref, sigma_ref, trellis=trellis, L=L,
-                         radix=radix, store=store, bm_dtype=bm_dtype)
-        return
-
     def store(t, sel, sigma):                        # sel/sigma are (FT, S)
         if pack:
             sel_ref[:, t, :] = pack_bits(sel)        # -> HBM, 1 bit/state
@@ -85,9 +61,8 @@ def forward_frames(frames: jax.Array, *, trellis: Trellis,
                    bm_dtype: str = "float32", interpret: bool = True):
     """(F, L, beta) llr -> (sel, amax (F, L) int32) in HBM.
 
-    sel layout/shape: lane (F, L, S) int8 or packed (F, L, ceil(S/32))
-    int32; sublane (L, S, F) int8 or packed (L*ceil(S/32), F) int32.
-    Interpret mode only: a compiled request raises ``ValueError``.
+    sel: (F, L, S) int8, or packed (F, L, ceil(S/32)) int32. Interpret mode
+    and the lane layout only: any other request raises ``ValueError``.
     """
     if not interpret:
         raise ValueError(
@@ -95,48 +70,30 @@ def forward_frames(frames: jax.Array, *, trellis: Trellis,
             "interpret mode: its per-stage column stores into the HBM "
             "argmax stream do not lower on Mosaic; use the unified kernel "
             "(backend='kernel') with interpret=False")
+    if Layout(layout) is not Layout.LANE:
+        raise ValueError(
+            "the split kernel (backend='kernel_split') runs only in the "
+            "lane layout; use layout='lane', or the unified kernel for "
+            "layout='sublane'")
     F, L, beta = frames.shape
     FT = frames_per_tile
     assert F % FT == 0, (F, FT)
     assert radix in (2, 4), radix
-    layout = Layout(layout)
     bm_dt = jnp.dtype(bm_dtype)
     S = trellis.num_states
     half = 1 << (trellis.beta - 1)
-    W = packed_width(S)
-    sub = layout is Layout.SUBLANE
-
-    if sub:
-        # one lane chunk of the whole tile (see acs.acs_scan_sublane)
-        inputs = (frames.astype(jnp.float32).reshape(F // FT, FT, L * beta)
-                  .transpose(0, 2, 1))
-        in_spec = pl.BlockSpec((1, L * beta, FT), lambda i: (i, 0, 0))
-        if pack_survivors:
-            sel_spec = pl.BlockSpec((L * W, FT), lambda i: (0, i))
-            sel_shape = jax.ShapeDtypeStruct((L * W, F), jnp.int32)
-        else:
-            sel_spec = pl.BlockSpec((L, S, FT), lambda i: (0, 0, i))
-            sel_shape = jax.ShapeDtypeStruct((L, S, F), jnp.int8)
-        scratch = [pltpu.VMEM((1, half * L, FT), bm_dt),
-                   pltpu.VMEM((1, S, FT), jnp.float32)]
-    else:
-        inputs = frames
-        in_spec = pl.BlockSpec((FT, L, beta), lambda i: (i, 0, 0))
-        sel_w = W if pack_survivors else S
-        sel_dt = jnp.int32 if pack_survivors else jnp.int8
-        sel_spec = pl.BlockSpec((FT, L, sel_w), lambda i: (i, 0, 0))
-        sel_shape = jax.ShapeDtypeStruct((F, L, sel_w), sel_dt)
-        scratch = [pltpu.VMEM((L, FT, half), bm_dt)]
-
+    sel_w = packed_width(S) if pack_survivors else S
+    sel_dt = jnp.int32 if pack_survivors else jnp.int8
     kern = functools.partial(_kernel, trellis=trellis, L=L,
-                             pack=pack_survivors, radix=radix, layout=layout,
-                             bm_dtype=bm_dt)
+                             pack=pack_survivors, radix=radix, bm_dtype=bm_dt)
     return pl.pallas_call(
         kern,
         grid=(F // FT,),
-        in_specs=[in_spec],
-        out_specs=[sel_spec, pl.BlockSpec((FT, L), lambda i: (i, 0))],
-        out_shape=[sel_shape, jax.ShapeDtypeStruct((F, L), jnp.int32)],
-        scratch_shapes=scratch,
+        in_specs=[pl.BlockSpec((FT, L, beta), lambda i: (i, 0, 0))],
+        out_specs=[pl.BlockSpec((FT, L, sel_w), lambda i: (i, 0, 0)),
+                   pl.BlockSpec((FT, L), lambda i: (i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((F, L, sel_w), sel_dt),
+                   jax.ShapeDtypeStruct((F, L), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((L, FT, half), bm_dt)],
         interpret=interpret,
-    )(inputs)
+    )(frames)

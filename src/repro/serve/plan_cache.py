@@ -40,6 +40,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from ..core.framed import flatten_bits
 from ..core.pipeline import DecoderConfig, _build_frame_decoder
 from ..obs.tracer import get_tracer
 
@@ -60,7 +61,7 @@ def build_window_fn(spec, decode_frames, nframes: int, trace_hook=None):
         starts = jnp.arange(nframes) * f
         idx = starts[:, None] + jnp.arange(L)[None, :]
         frames = window[idx]                    # (nframes, L, beta)
-        return decode_frames(frames).reshape(-1)
+        return flatten_bits(decode_frames(frames))
 
     return run
 

@@ -60,14 +60,19 @@ def test_rehearse_phase_d_four_devices(four_cpu_devices):
 
 
 def test_smoke_checks_fail_loudly():
-    """A phase whose config does not select the sublane kernel, or a
-    bucket that fell back to the reference, is a failure, not a pass."""
+    """A phase whose config does not select the sublane kernel, a kernel
+    traced in interpret mode, or a phase in which no kernel compiled is a
+    failure, not a pass."""
     with pytest.raises(chip_smoke.SmokeFailure, match="sublane kernel"):
         chip_smoke.kernel_cfg({"backend": "reference"},
                               spec=chip_smoke.SPEC_12)
     with pytest.raises(chip_smoke.SmokeFailure, match="interpret"):
         with chip_smoke.Traced(interpret=False) as tr:
             tr.tracer.event("kernel_trace", interpret=True)
+        tr.kernel_plans()
+    with pytest.raises(chip_smoke.SmokeFailure, match="no kernel_trace"):
+        with chip_smoke.Traced(interpret=False) as tr:
+            pass                             # e.g. a program traced before
         tr.kernel_plans()
 
 

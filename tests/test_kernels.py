@@ -103,28 +103,40 @@ def test_unified_kernel_knobs_match_ref(rng, pack, radix, layout):
     assert np.array_equal(got, want)
 
 
-@pytest.mark.parametrize("layout", ["lane", "sublane"])
 @pytest.mark.parametrize("pack", [False, True])
 @pytest.mark.parametrize("radix", [2, 4])
-def test_split_kernel_knobs_match_ref(rng, pack, radix, layout):
-    """The split path streams (possibly packed, possibly sublane-major)
-    survivors through HBM and traces back at the JAX level — same bits for
-    every knob combo."""
+def test_split_kernel_knobs_match_ref(rng, pack, radix):
+    """The split path streams (possibly packed) survivors through HBM and
+    traces back at the JAX level — same bits for every knob combo."""
     bits = rng.integers(0, 2, 600)
     spec = FrameSpec(f=64, v1=20, v2=20, f0=16, v2s=20)
     frames = _frames(bits, STD_K7, spec, rng)
     want = np.asarray(ref.unified_decode_frames_ref(frames, STD_K7, spec))
     got = np.asarray(ops.viterbi_decode_frames(
         frames, STD_K7, spec, unified=False, pack_survivors=pack,
-        radix=radix, layout=layout))
+        radix=radix, layout="lane"))
     assert np.array_equal(got, want)
 
 
-@pytest.mark.parametrize("unified", [True, False])
-@pytest.mark.parametrize("layout", ["lane", "sublane"])
+@pytest.mark.parametrize("ft", ["auto", 16])   # planner / kernel refuse
+def test_split_kernel_refuses_sublane_layout(ft):
+    """The split kernel has the lane layout only; a sublane request raises
+    instead of decoding in another layout, whether the planner sizes the
+    tile or the caller does."""
+    spec = FrameSpec(f=64, v1=16, v2=16, f0=16, v2s=16)
+    frames = jnp.zeros((16, spec.frame_len, 2), jnp.float32)
+    with pytest.raises(ValueError, match="only in the lane layout"):
+        ops.viterbi_decode_frames(frames, STD_K7, spec, unified=False,
+                                  frames_per_tile=ft, layout="sublane",
+                                  interpret=True)
+
+
+@pytest.mark.parametrize("unified,layout", [(True, "lane"),
+                                            (True, "sublane"),
+                                            (False, "lane")])
 def test_split_serial_traceback_layouts(rng, unified, layout):
-    """Serial-traceback specs exercise the batched serial chase in both
-    stream layouts (the sublane path has no vmap fallback)."""
+    """Serial-traceback specs exercise the batched serial chase of the
+    split path and the unified kernel's serial chase in both layouts."""
     bits = rng.integers(0, 2, 400)
     spec = FrameSpec(f=64, v1=16, v2=16)                  # serial tb
     frames = _frames(bits, STD_K7, spec, rng)
@@ -137,18 +149,16 @@ def test_split_serial_traceback_layouts(rng, unified, layout):
 @pytest.mark.parametrize("k,polys", [(4, (0o13, 0o15, 0o17)),   # S=8, beta=3
                                      (5, (0o23, 0o35))])        # S=16
 def test_small_state_codes_packed_sublane(rng, k, polys):
-    """S < 32 states pack into one zero-padded word; the sublane layout's
-    flat (L*1, FT) scratch and word extraction must stay exact."""
+    """S < 32 states pack into one zero-padded word; the unified kernel's
+    flat sublane (L*1, FT) scratch and word extraction must stay exact."""
     tr = make_trellis(k, polys)
     bits = rng.integers(0, 2, 400)
     spec = FrameSpec(f=64, v1=16, v2=16, f0=16, v2s=16)
     frames = _frames(bits, tr, spec, rng, snr=6.0)
     want = np.asarray(ref.unified_decode_frames_ref(frames, tr, spec))
-    for unified in (True, False):
-        got = np.asarray(ops.viterbi_decode_frames(
-            frames, tr, spec, unified=unified, pack_survivors=True, radix=4,
-            layout="sublane"))
-        assert np.array_equal(got, want), unified
+    got = np.asarray(ops.viterbi_decode_frames(
+        frames, tr, spec, pack_survivors=True, radix=4, layout="sublane"))
+    assert np.array_equal(got, want)
 
 
 def test_deep_tile_ft256(rng):
@@ -169,11 +179,12 @@ def test_deep_tile_ft256(rng):
 def test_bf16_branch_metrics_decode(rng, layout):
     """bf16 branch metrics are not bit-exact, but at a clean SNR the
     decoded bits must still round-trip, and the knob must work on both
-    kernels and layouts (test_ber.py bounds the noisy-channel BER delta)."""
+    kernels and layouts (test_ber.py bounds the noisy-channel BER delta;
+    the split kernel has the lane layout only)."""
     bits = rng.integers(0, 2, 640)
     spec = FrameSpec(f=64, v1=20, v2=20, f0=16, v2s=20)
     frames = _frames(bits, STD_K7, spec, rng, snr=8.0)
-    for unified in (True, False):
+    for unified in (True, False) if layout == "lane" else (True,):
         got = np.asarray(ops.viterbi_decode_frames(
             frames, STD_K7, spec, unified=unified, layout=layout,
             bm_dtype="bfloat16"))

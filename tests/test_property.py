@@ -61,7 +61,7 @@ def test_kernel_variants_bit_identical_to_reference(seed, knobs, ft, layout):
     """EVERY float32 kernel configuration — packed/unpacked survivors,
     radix-2/4, lane/sublane layout, any tile size — must decode random
     LLRs bit-identically to the core.decoder-based oracle, on both the
-    unified and split paths."""
+    unified and split paths (the split path has the lane layout only)."""
     from repro.core.framed import frame_llr
     from repro.kernels import ops, ref
     pack, radix = knobs
@@ -74,7 +74,8 @@ def test_kernel_variants_bit_identical_to_reference(seed, knobs, ft, layout):
                       .astype(np.float32))          # pure noise: worst case
     frames = frame_llr(llr, spec)
     want = np.asarray(ref.unified_decode_frames_ref(frames, STD_K7, spec))
-    unified = bool(seed & 1)                        # alternate the two paths
+    # alternate the two paths in the lane layout
+    unified = bool(seed & 1) or layout == "sublane"
     got = np.asarray(ops.viterbi_decode_frames(
         frames, STD_K7, spec, unified=unified, frames_per_tile=ft,
         pack_survivors=pack, radix=radix, layout=layout))

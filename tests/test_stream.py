@@ -58,7 +58,9 @@ def test_stream_decoder_is_reusable_after_flush(rng):
 def test_stream_kernel_backends(rng, backend):
     n = 2000
     llr, _ = _llr(n, rng)
-    cfg = DecoderConfig(spec=SPEC, backend=backend, layout="sublane")
+    # the split kernel has the lane layout only
+    layout = "lane" if backend == "kernel_split" else "sublane"
+    cfg = DecoderConfig(spec=SPEC, backend=backend, layout=layout)
     want = np.asarray(make_decoder(cfg)(jnp.asarray(llr), n))
     got = stream_decode(cfg, llr, n, chunk_frames=8)
     assert np.array_equal(got, want)

@@ -10,6 +10,8 @@ The topology is described inside a fixture, never while a module is
 imported (only one process may load the TPU library at a time), and the
 persistent compilation cache is off: a compile for a described chip can
 be written to it but not read back."""
+import os
+
 import pytest
 
 import jax
@@ -35,6 +37,8 @@ K7 = (7, (0o171, 0o133))
 def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
+    # the TPU library's logs would otherwise go to a fixed path under /tmp
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
         desc = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
