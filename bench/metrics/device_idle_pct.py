@@ -1,0 +1,9 @@
+"""device_idle_pct: the share of the traced window in which no operation
+ran on the device (1 - the union of operation intervals over the
+window), averaged over the chips used."""
+
+
+def read(run):
+    if run.trace is None or not run.trace["window_s"]:
+        return None
+    return 100.0 * (1.0 - run.trace["busy_s"] / run.trace["window_s"])
