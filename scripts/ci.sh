@@ -383,7 +383,6 @@ for e in ev:
 b = [e["id"] for e in ev if e["ph"] == "b"]
 e_ = [e["id"] for e in ev if e["ph"] == "e"]
 assert b and sorted(b) == sorted(e_), (len(b), len(e_))
-assert obj["otherData"]["counters"]["plan_cache_misses"] > 0
 print(f"obs smoke: {len(ev)} events, {len(b)} async pairs, "
       f"spans {sorted(names - {'process_name'})}")
 
@@ -403,6 +402,11 @@ snap = json.load(open(art + "/serve_metrics.json"))
 hist = snap["stages_hist"]["launch_ms"]
 assert hist["buckets"][-1][0] == "+Inf"
 assert hist["buckets"][-1][1] == hist["count"] > 0
+# the plan cache's misses (PlanCache.stats(), not a tracer counter) reach
+# both halves of the pair
+assert snap["plan_cache"]["misses"] > 0, snap["plan_cache"]
+m = re.search(r"^repro_serve_plan_cache_misses (\S+)$", prom, re.M)
+assert m and float(m.group(1)) == snap["plan_cache"]["misses"], m
 print(f"obs smoke: exposition {len(prom.splitlines())} lines, "
       f"{len(snap['stages_hist'])} stage histograms")
 print("OBS_SMOKE_OK")

@@ -17,8 +17,8 @@ decode pipeline:
   * ``event(name, **attrs)`` — an instant (a retry, a trace-time kernel
     specialization).
 
-``count(name, n)`` bumps a named counter (plan-cache hits, kernel
-traces); counters ride along in the exported trace metadata.
+``count(name, n)`` bumps a named counter (kernel traces, tune-DB
+hits); counters ride along in the exported trace metadata.
 
 The pay-nothing contract (same as ``faults=`` in the serve layer): the
 process-global tracer defaults to ``NULL_TRACER``, whose ``span``/

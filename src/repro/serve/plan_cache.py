@@ -86,21 +86,18 @@ class PlanCache:
         """Cached build. ``refresh=True`` drops any existing entry first —
         the fault-injection harness uses it to force the cold path (an
         evicted / never-compiled plan) on a live server. Misses time the
-        build under a ``plan_build`` span; hits/misses bump the tracer's
-        counters so a trace file alone tells the cache story."""
-        trace = get_tracer()
+        build under a ``plan_build`` span; ``stats()`` counts hits, misses
+        and traces."""
         with self._lock:
             if refresh:
                 self._fns.pop(key, None)
             fn = self._fns.get(key)
             if fn is not None:
                 self.hits += 1
-                trace.count("plan_cache_hits")
                 return fn
             self.misses += 1
-            trace.count("plan_cache_misses")
         t0 = time.perf_counter()
-        with trace.span("plan_build", kind=str(key[0])):
+        with get_tracer().span("plan_build", kind=str(key[0])):
             fn = build()                        # build outside the lock
         dt_ms = (time.perf_counter() - t0) * 1e3
         with self._lock:
@@ -110,7 +107,6 @@ class PlanCache:
     def _mark_trace(self):
         with self._lock:
             self.traces += 1
-        get_tracer().count("plan_cache_traces")
 
     def stats(self) -> dict:
         with self._lock:

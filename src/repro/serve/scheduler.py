@@ -172,6 +172,11 @@ class Session:
         """Feed raw input through the context; queue every completed
         window on the bucket. Returns windows queued."""
         self.ctx.append(llr)
+        return self.queue_windows()
+
+    def queue_windows(self) -> int:
+        """Queue every window the context has completed on the bucket
+        (``absorb`` after its ``ctx.append``). Returns windows queued."""
         windows = self.ctx.take_windows()
         for w in windows:
             self._enqueue(w)

@@ -209,10 +209,20 @@ class DecodeServer:
                   quarantined.
     faults:       optional repro.testing.faults.FaultInjector (tests/CI
                   chaos only; None in production).
-    trace:        optional repro.obs.Tracer recording push/launch/retry/
-                  retire spans and stage latencies. None (default)
-                  resolves to the process-global tracer — a pay-nothing
-                  no-op unless ``repro.obs.set_tracer`` enabled one.
+    trace:        optional tracer: a repro.obs.Tracer records spans into
+                  its ring; a repro.obs.ProfilerTracer (the profiler sink)
+                  turns each sync span into a ``repro.<name>`` profiler
+                  annotation. Spans: ``push`` (around ``push_sanitize``,
+                  ``push_admit``, ``push_stage``, ``push_frame``, which
+                  the disabled tracer is never asked for),
+                  ``launch`` (around ``batch_pack``, ``h2d``,
+                  ``launch_attempt``/``degrade``), ``retire`` (around
+                  ``retire_wait``), ``evacuate``, ``readmit``,
+                  ``breaker_probe``; async ``inflight``; instants
+                  ``retry``, ``breaker_open``, ``breaker_close``. None
+                  (default) resolves to the process-global tracer — a
+                  pay-nothing no-op unless ``repro.obs.set_tracer``
+                  installed one.
     """
 
     def __init__(self, *, slots: int = 4, max_sessions: int = 64,
@@ -414,21 +424,46 @@ class DecodeServer:
         if session.quarantined is not None:
             raise SessionQuarantined(sid, session.quarantined,
                                      session.strikes)
-        with self.trace.span("push", sid=sid, bucket=session.bucket.id) as sp:
+        trace = self.trace
+        with trace.span("push", sid=sid, bucket=session.bucket.id) as sp:
             if self.faults is not None:
                 llr = self.faults.corrupt(llr, sid=sid)
+            if trace.enabled:
+                sp.set(windows=self._push_parts(trace, session, llr))
+                return
+            # untraced: the same steps with no sub-span to enter
             llr = self._validate_push(session, llr)
             projected = session.ctx.projected_windows(
                 session.ctx.incoming_stages(llr))
             if session.inflight + projected > self.queue_depth:
-                overshoot = session.inflight + projected - self.queue_depth
-                raise Backpressure(
-                    f"session {sid}: {session.inflight} windows pending + "
-                    f"{projected} in this push > queue_depth="
-                    f"{self.queue_depth}; call step() and retry (or split "
-                    f"pushes larger than queue_depth chunks)",
-                    retry_after_steps=max(1, -(-overshoot // self.slots)))
+                self._refuse(session, projected)
             sp.set(windows=session.absorb(llr))
+
+    def _push_parts(self, trace, session: Session, llr) -> int:
+        """``push``'s steps under a tracer that records, each in its
+        sub-span; returns the windows queued."""
+        with trace.span("push_sanitize"):
+            llr = self._validate_push(session, llr)
+        with trace.span("push_admit"):
+            projected = session.ctx.projected_windows(
+                session.ctx.incoming_stages(llr))
+            if session.inflight + projected > self.queue_depth:
+                self._refuse(session, projected)
+        with trace.span("push_stage"):
+            session.ctx.append(llr)
+        with trace.span("push_frame"):
+            return session.queue_windows()
+
+    def _refuse(self, session: Session, projected: int):
+        """Raise ``Backpressure`` for a push of ``projected`` windows that
+        would overrun the session's ``queue_depth``."""
+        overshoot = session.inflight + projected - self.queue_depth
+        raise Backpressure(
+            f"session {session.sid}: {session.inflight} windows pending + "
+            f"{projected} in this push > queue_depth={self.queue_depth}; "
+            f"call step() and retry (or split pushes larger than "
+            f"queue_depth chunks)",
+            retry_after_steps=max(1, -(-overshoot // self.slots)))
 
     def step(self) -> int:
         """One batched launch per bucket with pending windows, dispatched
@@ -569,7 +604,8 @@ class DecodeServer:
         launch."""
         B = batch.shape[0]
         bm = self.metrics.bucket(bucket.id)
-        dev = jnp.asarray(batch)
+        with self.trace.span("h2d"):
+            dev = jnp.asarray(batch)
         if bucket.pinned:
             # failover path: probe the primary when its breaker is ready,
             # otherwise decode on the pinned reference backend. Neither
@@ -673,7 +709,8 @@ class DecodeServer:
             with self.trace.span("retire", bucket=bucket.id,
                                  windows=len(taken)):
                 try:
-                    bits = np.asarray(bits_dev)         # (k*C, f)
+                    with self.trace.span("retire_wait"):
+                        bits = np.asarray(bits_dev)     # (k*C, f)
                 except Exception as e:                  # noqa: BLE001
                     bm.record_fault("launch_errors", error=repr(e))
                     bm.record_fault("degraded")

@@ -186,7 +186,7 @@ def test_chrome_trace_schema_and_async_pairing(tmp_path):
         t.event("retry", attempt=1)
     h = t.begin("inflight", frames=8)
     h.end()
-    t.count("plan_cache_hits", 3)
+    t.count("kernel_traces", 3)
     path = tmp_path / "trace.json"
     write_chrome_trace(t, str(path))
     obj = json.loads(path.read_text())          # valid JSON on disk
@@ -203,7 +203,7 @@ def test_chrome_trace_schema_and_async_pairing(tmp_path):
     assert begins[0]["id"] == ends[0]["id"]
     (inst,) = [e for e in ev if e["ph"] == "i"]
     assert inst["name"] == "retry"
-    assert obj["otherData"]["counters"] == {"plan_cache_hits": 3}
+    assert obj["otherData"]["counters"] == {"kernel_traces": 3}
 
 
 def test_chrome_trace_stringifies_exotic_attr_values():
@@ -341,6 +341,117 @@ def test_server_spans_nest_and_stage_breakdown_lands_in_snapshot():
     assert all(row["uptime_s"] > 0 for row in snap["buckets"])
 
 
+#: the sync spans inside push, _dispatch and _retire
+PUSH_PARTS = ("push_sanitize", "push_admit", "push_stage", "push_frame")
+
+
+def test_server_sub_spans_nest_under_push_launch_and_retire():
+    t = Tracer()
+    srv, sids = _serve_workload(t)
+    for sid in sids:
+        srv.close_session(sid)
+    recs = [r for r in t.spans() if r.kind == "span"]
+    parent = {}
+    for r in recs:
+        parent.setdefault(r.name, set()).add(r.parent)
+    for name in PUSH_PARTS:
+        assert parent[name] == {"push"}, name
+    assert parent["h2d"] == {"launch"}
+    assert parent["retire_wait"] == {"retire"}
+    n = {name: sum(r.name == name for r in recs)
+         for name in PUSH_PARTS + ("push", "launch", "h2d", "retire",
+                                   "retire_wait")}
+    assert len({n[k] for k in ("push",) + PUSH_PARTS}) == 1
+    assert n["h2d"] == n["launch"] and n["retire_wait"] == n["retire"]
+
+
+def test_null_tracer_serve_call_sites_share_one_no_op():
+    """With the disabled tracer every span the server opens is the one
+    shared no-op object, and ``push`` opens none of its sub-spans."""
+    from repro.obs.tracer import _NULL_SPAN
+
+    class Spy(NullTracer):
+        def __init__(self):
+            self.got = []
+
+        def span(self, name, **attrs):
+            out = super().span(name, **attrs)
+            self.got.append((name, out))
+            return out
+
+    spy = Spy()
+    srv, sids = _serve_workload(spy)
+    for sid in sids:
+        srv.close_session(sid)
+    names = {name for name, _ in spy.got}
+    assert {"push", "h2d", "retire_wait"} <= names
+    assert not names & set(PUSH_PARTS)
+    assert all(out is _NULL_SPAN for _, out in spy.got)
+    srv, _ = _serve_workload(None)               # the default
+    assert srv.trace is NULL_TRACER
+
+
+@pytest.mark.parametrize("kind", ["null", "ring", "profiler"])
+def test_push_paths_traced_and_untraced_agree(kind):
+    """``push`` under each kind of tracer against the untraced default:
+    the same bits back, and the same backpressure refusal before anything
+    is absorbed."""
+    from repro.core import DecoderConfig, FrameSpec
+    from repro.obs import ProfilerTracer
+    from repro.serve import Backpressure
+    trace = {"null": NULL_TRACER, "ring": Tracer(),
+             "profiler": ProfilerTracer()}[kind]
+    srv, sids = _serve_workload(trace)
+    ref, ref_sids = _serve_workload(None)
+    for a, b in zip(sids, ref_sids):
+        got = np.concatenate([srv.poll(a), srv.close_session(a)])
+        want = np.concatenate([ref.poll(b), ref.close_session(b)])
+        assert got.size and np.array_equal(got, want)
+    cfg = DecoderConfig(spec=FrameSpec(f=64, v1=16, v2=20, f0=16, v2s=20))
+    sid = srv.open_session(cfg, chunk_frames=5)
+    big = np.zeros((64 * 5 * (srv.queue_depth + 2), 2), np.float32)
+    with pytest.raises(Backpressure, match="queue_depth"):
+        srv.push(sid, big)
+    assert srv.session_state(sid)["inflight"] == 0
+    srv.push(sid, big[:64 * 5 * 2])              # nothing was absorbed
+    assert srv.session_state(sid)["inflight"] == 1
+
+
+def test_profiler_sink_names_spans_for_the_profiler(tmp_path):
+    """Installed through set_tracer, the sink turns each sync span into a
+    ``repro.<name>`` profiler annotation, nested as the spans are; the
+    ring, counters and async spans stay empty."""
+    from repro.obs import ProfilerTracer
+    sink = ProfilerTracer()
+    set_tracer(sink)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        srv, sids = _serve_workload(None)
+        assert srv.trace is sink
+        for sid in sids:
+            srv.close_session(sid)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    evs = [e for p in jax.profiler.ProfileData.from_file(str(path)).planes
+           for ln in p.lines for e in ln.events
+           if e.name.startswith("repro.")]
+    names = {e.name for e in evs}
+    assert {"repro." + n for n in ("push", "launch", "batch_pack", "h2d",
+                                   "launch_attempt", "retire",
+                                   "retire_wait") + PUSH_PARTS} <= names
+    pushes = [e for e in evs if e.name == "repro.push"]
+    for part in [e for e in evs if e.name in
+                 {"repro." + n for n in PUSH_PARTS}]:
+        assert any(p.start_ns <= part.start_ns
+                   and part.start_ns + part.duration_ns
+                   <= p.start_ns + p.duration_ns for p in pushes)
+    assert sink.spans() == [] and sink.counters() == {}
+    with sink.span("x") as sp:
+        assert sp.set(a=1) is sp
+    assert sink.begin("y") is sink.begin("z")    # async: the shared no-op
+
+
 def test_server_retry_and_degrade_spans_under_faults():
     from repro.testing import FaultInjector, FaultSpec
     t = Tracer()
@@ -420,10 +531,11 @@ def test_plan_cache_counts_hits_misses_and_build_time():
     cfg = DecoderConfig(spec=FrameSpec(f=64, v1=16, v2=20, f0=16, v2s=20))
     cache.frame_decoder(cfg)
     cache.frame_decoder(cfg)
-    c = t.counters()
-    assert c["plan_cache_misses"] == 1 and c["plan_cache_hits"] == 1
-    assert any(r.name == "plan_build" for r in t.spans())
-    assert cache.stats()["build_ms"] >= 0.0
+    st = cache.stats()
+    assert st["misses"] == 1 and st["hits"] == 1
+    assert [r.name for r in t.spans()] == ["plan_build"]
+    assert t.counters() == {}                # the stats are the counters
+    assert st["build_ms"] >= 0.0
 
 
 def test_record_fault_rejects_unknown_counter():
