@@ -49,6 +49,7 @@ from __future__ import annotations
 import base64
 import collections
 import dataclasses
+import functools
 import zlib
 
 import numpy as np
@@ -91,12 +92,49 @@ class Window:
     n_bits: int
 
     def frames(self, spec) -> np.ndarray:
-        """Frame the window host-side: (nframes, L, beta). Pure gather —
-        identical values to the jitted in-graph framing, so a batch built
-        from these frames decodes bit-identically."""
-        starts = np.arange(self.nframes) * spec.f
-        idx = starts[:, None] + np.arange(spec.frame_len)[None, :]
-        return self.window[idx]
+        """The window's frames, (nframes, L, beta): frame m is rows
+        ``[m*f, m*f + L)``, taken as a read-only strided view with no
+        copy and no index array. Identical values to the jitted in-graph
+        framing, so a batch built from these frames decodes
+        bit-identically. The view aliases the context's buffer, which is
+        never written in place (``StreamContext`` only rebuilds or
+        re-slices it), so a queued window keeps its values."""
+        if self.nframes == 1:
+            return self.window[None]
+        rows, cols = self.window.strides
+        return np.lib.stride_tricks.as_strided(
+            self.window, (self.nframes, spec.frame_len, self.window.shape[1]),
+            (spec.f * rows, rows, cols), writeable=False)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Period:
+    """One period of a puncturing pattern as staging reads it: ``P``
+    stages keep ``K`` symbols. From phase p, ``kept[p][j]`` symbols fill
+    the next j stages (j = 0..P), ``stages[p][m]`` stages are complete
+    with m < K symbols, and ``pos[p]`` holds the offsets of the K kept
+    symbols, in stream order, within P stages of beta symbols."""
+    P: int
+    K: int
+    kept: tuple
+    stages: tuple
+    pos: tuple
+
+
+@functools.cache
+def _period(rate: str) -> _Period:
+    pat = PATTERNS[rate]
+    P = pat.shape[1]
+    kept, stages, pos = [], [], []
+    for p in range(P):
+        mask = np.roll(pat, -p, axis=1).T            # (P, beta) from phase p
+        cum = np.concatenate([[0], np.cumsum(mask.sum(axis=1))])
+        kept.append(tuple(int(c) for c in cum))
+        stages.append(tuple(int(np.searchsorted(cum[1:], m, side="right"))
+                            for m in range(int(cum[-1]))))
+        pos.append(np.flatnonzero(mask.reshape(-1)))
+        pos[-1].flags.writeable = False              # shared by every caller
+    return _Period(P, kept[0][-1], tuple(kept), tuple(stages), tuple(pos))
 
 
 class StreamContext:
@@ -258,40 +296,61 @@ class StreamContext:
         self.n_sanitized = int(state["n_sanitized"])
 
     # -- depuncturing (stream-global phase) -------------------------------
-    def _stage_counts(self, t_max: int) -> np.ndarray:
-        """Kept symbols per stage for the next ``t_max`` stages (cyclic in
-        the pattern period, offset by the stream-global phase)."""
-        pat = PATTERNS[self.rate]
-        per_stage = pat.sum(axis=0)             # kept symbols at phase t
-        return per_stage[(self._phase + np.arange(t_max)) % pat.shape[1]]
+    def _complete_stages(self, r: int) -> int:
+        """Complete stages ``r`` raw symbols fill from the current phase."""
+        per = _period(self.rate)
+        q, m = divmod(r, per.K)
+        return q * per.P + per.stages[self._phase % per.P][m]
 
-    def _depuncture(self, final: bool) -> np.ndarray:
-        """Convert buffered raw symbols into complete (s, beta) stages.
+    def _stage(self, new: np.ndarray, final: bool) -> int:
+        """Depuncture the raw carry plus ``new`` symbols into complete
+        stages, written straight into a fresh ``_buf`` after its carried
+        rows; returns the stages added.
 
         Bit-identical to one-shot ``puncture.depuncture`` of the whole
-        stream: punctured positions become neutral zero LLRs. ``final``
-        also emits a trailing stage the remainder only partly fills
-        (missing kept symbols become zeros — an erased tail)."""
-        pat = PATTERNS[self.rate]
-        period = pat.shape[1]
-        r = self._raw.shape[0]
-        if r == 0:
-            return np.zeros((0, self.beta), np.float32)
-        t_max = r + period                       # >= any reachable stage count
-        cum = np.cumsum(self._stage_counts(t_max))
-        s = int(np.searchsorted(cum, r, side="right"))
-        if final and (s == 0 or cum[s - 1] < r):
-            s += 1                               # partial last stage
-        if s == 0:
-            return np.zeros((0, self.beta), np.float32)
-        used = int(min(cum[s - 1], r))
-        p0 = self._phase % period
-        mask = np.tile(pat, (1, -(-(p0 + s) // period))).T[p0:p0 + s]
-        flat = np.zeros((s * self.beta,), np.float32)
-        flat[np.flatnonzero(mask.reshape(-1))[:used]] = self._raw[:used]
-        self._raw = self._raw[used:]
+        stream: punctured positions become neutral zero LLRs. The stages
+        come in three pieces: a head up to the next period boundary (a
+        whole period when carried symbols start on the boundary, so the
+        carry never reaches the body), a body of whole periods written as
+        one (n, K) -> (n, P*beta) column assignment, and a tail of at most
+        one period. ``final`` also emits a trailing stage the remainder
+        only partly fills (missing kept symbols become zeros — an erased
+        tail)."""
+        per = _period(self.rate)
+        P, K = per.P, per.K
+        carry = self._raw
+        c = carry.shape[0]
+        p0 = self._phase % P
+        s = self._complete_stages(c + new.shape[0])
+        used = (s // P) * K + per.kept[p0][s % P]
+        if final and used < c + new.shape[0]:
+            s, used = s + 1, c + new.shape[0]         # partial last stage
+        if s == 0:                                    # under one stage
+            self._raw = np.concatenate([carry, new])
+            return 0
+        rows = self._buf.shape[0]
+        buf = np.zeros((rows + s, self.beta), np.float32)
+        buf[:rows] = self._buf
+        out = buf[rows:]
+        h = min(s, (-p0) % P or (P if c else 0))
+        m = min(per.kept[p0][h], used)                # head symbols, >= c
+        if h:
+            out[:h].reshape(-1)[per.pos[p0][:m]] = np.concatenate(
+                [carry, new[:m - c]])
+        a = m - c                                     # new symbols consumed
+        n = min((s - h) // P, (used - m) // K)
+        if n:
+            out[h:h + n * P].reshape(n, P * self.beta)[:, per.pos[0]] = \
+                new[a:a + n * K].reshape(n, K)
+            a += n * K
+        k = used - c - a
+        if k:
+            out[h + n * P:].reshape(-1)[per.pos[0][:k]] = new[a:a + k]
+        self._raw = new[used - c:].copy()             # may alias the caller
+        self._buf = buf
         self._phase += s
-        return flat.reshape(s, self.beta)
+        self.n_in += s
+        return s
 
     # -- input / window extraction ----------------------------------------
     def append(self, llr) -> int:
@@ -306,10 +365,8 @@ class StreamContext:
             llr, n_bad = sanitize_llr(llr, self.llr_clip, self.sanitize)
             self.n_sanitized += n_bad
         if self.rate != "1/2":
-            self._raw = np.concatenate([self._raw, llr.reshape(-1)])
-            staged = self._depuncture(final=False)
-        else:
-            staged = llr.reshape(-1, self.beta)
+            return self._stage(llr.reshape(-1), final=False)
+        staged = llr.reshape(-1, self.beta)
         if staged.size:
             self._buf = np.concatenate([self._buf, staged])
             self.n_in += staged.shape[0]
@@ -322,11 +379,7 @@ class StreamContext:
         llr = np.asarray(llr)
         if self.rate == "1/2":
             return llr.size // self.beta
-        r = self._raw.shape[0] + llr.size
-        if r == 0:
-            return 0
-        cum = np.cumsum(self._stage_counts(r + PATTERNS[self.rate].shape[1]))
-        return int(np.searchsorted(cum, r, side="right"))
+        return self._complete_stages(self._raw.shape[0] + llr.size)
 
     def projected_windows(self, add_stages: int) -> int:
         """Complete chunk windows extractable once ``add_stages`` more
@@ -351,10 +404,7 @@ class StreamContext:
         """Flush-time prelude: convert any leftover raw punctured symbols
         (including a partly-filled final stage) into buffered stages."""
         if self.rate != "1/2" and self._raw.size:
-            staged = self._depuncture(final=True)
-            if staged.size:
-                self._buf = np.concatenate([self._buf, staged])
-                self.n_in += staged.shape[0]
+            self._stage(np.zeros((0,), np.float32), final=True)
 
     def flush_window(self) -> Window | None:
         """The zero-padded final partial chunk (frame_llr's edge padding)

@@ -134,7 +134,8 @@ def bucket_plan(cfg: DecoderConfig, num_devices: int = 1,
 class PendingWindow:
     """One chunk window queued for a batched launch."""
     session: "Session"
-    frames: np.ndarray            # (chunk_frames, L, beta) float32
+    frames: np.ndarray            # (chunk_frames, L, beta) float32, a view
+                                  # of the session's buffer (Window.frames)
     n_bits: int                   # real bits (tail windows carry padding)
     t_enq: float                  # perf_counter at enqueue: queue_wait_ms
                                   # stage + end-to-end latency both start here
