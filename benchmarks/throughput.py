@@ -231,7 +231,7 @@ def _serve_workload(full: bool):
     (streams, total_bits, nbuckets, C, nchunks, nsess) where streams is
     [(cfg, [chunk0, chunk1, ...], n_bits), ...]."""
     from repro.core import DecoderConfig
-    from repro.core.puncture import PATTERNS
+    from repro.core.puncture import pattern
     from repro.core.trellis import make_trellis
 
     C = 16                                     # chunk frames per session
@@ -252,8 +252,8 @@ def _serve_workload(full: bool):
     streams = []                               # (cfg, raw chunks, n_bits)
     for cfg in mix:
         n = C * cfg.spec.f * nchunks           # stages == bits
-        if cfg.rate != "1/2":
-            pat = PATTERNS[cfg.rate]
+        if cfg.punctured:
+            pat = pattern(cfg.rate)
             m = n * pat.sum() // pat.shape[1]  # raw punctured symbols
             raw = rng.standard_normal(m).astype(np.float32)
             per = m // nchunks

@@ -104,7 +104,7 @@ def reference(cfg: DecoderConfig, stream, n: int) -> np.ndarray:
     dec = make_decoder(dataclasses.replace(cfg, backend="reference"))
     with jax.default_device(host):
         stream = jax.device_put(np.asarray(stream), host)
-        if stream.ndim > (2 if cfg.rate == "1/2" else 1):
+        if stream.ndim > (1 if cfg.punctured else 2):
             return np.asarray(jax.vmap(lambda s: dec(s, n))(stream))
         return np.asarray(dec(stream, n))
 

@@ -151,10 +151,10 @@ rng = np.random.default_rng(0)
 def rx_for(cfg, n, seed):
     bits = jnp.asarray(rng.integers(0, 2, n))
     coded = encode(bits, cfg.trellis)
-    tx = bpsk(puncture(coded, cfg.rate)) if cfg.rate != "1/2" \
+    tx = bpsk(puncture(coded, cfg.rate)) if cfg.punctured \
         else bpsk(coded.reshape(-1))
     r = np.asarray(awgn(jax.random.PRNGKey(seed), tx, 4.0))
-    return r if cfg.rate != "1/2" else r.reshape(n, 2)
+    return r if cfg.punctured else r.reshape(n, 2)
 
 cache = PlanCache()
 srv = DecodeServer(slots=3, cache=cache)

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.encoder import encode
-from ..core.puncture import puncture, depuncture, punctured_rate
+from ..core.puncture import check_rate, depuncture, puncture, punctured_rate
 from ..core.trellis import Trellis, STD_K7
 
 __all__ = ["bpsk", "awgn", "ber", "simulate", "theoretical_ber",
@@ -53,7 +53,7 @@ def _channel(key, n: int, ebn0_db: float, rate: str, trellis: Trellis):
 
 def simulate(key: jax.Array, n: int, ebn0_db: float,
              decoder: Callable[[jax.Array], jax.Array],
-             rate: str = "1/2", trellis: Trellis = STD_K7,
+             rate: str | None = None, trellis: Trellis = STD_K7,
              hard: bool = False):
     """Run Fig. 8 once; returns (ber, bits, decoded).
 
@@ -62,8 +62,10 @@ def simulate(key: jax.Array, n: int, ebn0_db: float,
     ``hard=True`` slices the soft symbols to ±1 (hard-decision mode,
     paper §II-C — costs ~2.3 dB of BER).
     BER is trustworthy only when it exceeds 100/n (paper's rule of thumb).
+    ``rate`` None is the trellis's unpunctured 1/beta.
     """
-    bits, llr = _channel(key, n, ebn0_db, rate, trellis)
+    bits, llr = _channel(key, n, ebn0_db, check_rate(rate, trellis.beta),
+                         trellis)
     if hard:
         llr = jnp.sign(llr)
     decoded = decoder(llr)

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from .framed import FrameSpec, decode_frame, flatten_bits, frame_llr
-from .puncture import depuncture, check_alignment
+from .puncture import check_alignment, check_rate, depuncture, unpunctured
 from .sanitize import LLR_CLIP as _LLR_CLIP
 from .trellis import Trellis, STD_K7
 
@@ -59,6 +59,11 @@ class DecoderConfig:
     NOT bit-exact, but BER-neutral to within 1e-3 at Eb/N0 >= 2 dB
     (tests/test_ber.py gates it).
 
+    ``rate`` names the puncturing pattern over the trellis's beta
+    generators; None (the default) is the unpunctured ``1/beta`` —
+    ``"1/2"`` on a rate-1/2 trellis, ``"1/3"`` on a rate-1/3 one. A
+    pattern whose row count is not beta raises ValueError.
+
     ``renorm_every`` is the path-metric renormalization period: 1
     (default) subtracts the stage max every ACS stage — the historical
     behavior and what the Pallas kernels always do; N>1 amortizes the max
@@ -78,7 +83,7 @@ class DecoderConfig:
     """
     trellis: Trellis = STD_K7
     spec: FrameSpec = FrameSpec()
-    rate: str = "1/2"
+    rate: str | None = None        # puncturing pattern; None = 1/beta
     backend: str = _platform("backend")   # 'reference'|'kernel'|'kernel_split'
     interpret: bool = _platform("interpret")   # Pallas interpret mode
     pack_survivors: bool = True    # bit-pack survivors 32x (kernel backends)
@@ -91,7 +96,9 @@ class DecoderConfig:
     overlap: int | None = None     # block training/truncation stages
 
     def __post_init__(self):
-        if self.rate != "1/2":
+        object.__setattr__(self, "rate",
+                           check_rate(self.rate, self.trellis.beta))
+        if self.punctured:
             check_alignment(self.spec.f, self.spec.v1, self.spec.v2, self.rate)
         if self.radix not in (2, 4):
             raise ValueError(f"radix must be 2 or 4, got {self.radix}")
@@ -123,6 +130,12 @@ class DecoderConfig:
             from ..kernels.block import resolve_block
             resolve_block(self.trellis, self.spec, self.block_frames,
                           self.overlap)
+
+    @property
+    def punctured(self) -> bool:
+        """Whether ``rate`` drops symbols (pushes are then the raw
+        punctured stream, depunctured in-stream)."""
+        return self.rate != unpunctured(self.trellis.beta)
 
 
 def _build_frame_decoder(cfg: DecoderConfig):
@@ -186,14 +199,15 @@ def make_decoder(cfg: DecoderConfig):
 
     @partial(jax.jit, static_argnums=(1,))
     def decode(stream: jax.Array, n: int) -> jax.Array:
-        """stream: punctured soft symbols (m,) for rate!=1/2, or (n,beta)."""
+        """stream: punctured soft symbols (m,) for a punctured rate, or
+        (n, beta)."""
         # in-graph input hardening (core.sanitize): NaN/Inf -> neutral
         # zero, |llr| > clip -> ±clip. Identity on clean in-range inputs,
         # so the clean path stays bit-identical.
         stream = jnp.clip(
             jnp.where(jnp.isfinite(stream), stream, jnp.zeros_like(stream)),
             -_LLR_CLIP, _LLR_CLIP)
-        if cfg.rate != "1/2":
+        if cfg.punctured:
             llr = depuncture(stream, cfg.rate, n)
         else:
             llr = stream if stream.ndim == 2 else stream.reshape(n, -1)

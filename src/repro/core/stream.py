@@ -56,7 +56,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from .pipeline import DecoderConfig
-from .puncture import PATTERNS
+from .puncture import check_rate, pattern, unpunctured
 from .sanitize import LLR_CLIP, sanitize_llr
 
 __all__ = ["StreamContext", "StreamDecoder", "Window", "make_stream_decoder",
@@ -123,7 +123,7 @@ class _Period:
 
 @functools.cache
 def _period(rate: str) -> _Period:
-    pat = PATTERNS[rate]
+    pat = pattern(rate)
     P = pat.shape[1]
     kept, stages, pos = [], [], []
     for p in range(P):
@@ -161,13 +161,14 @@ class StreamContext:
     scan (the serve layer pre-sanitizes at its own boundary).
     """
 
-    def __init__(self, spec, beta: int, chunk_frames: int, rate: str = "1/2",
-                 *, sanitize: str = "zero", llr_clip: float = LLR_CLIP):
+    def __init__(self, spec, beta: int, chunk_frames: int,
+                 rate: str | None = None, *, sanitize: str = "zero",
+                 llr_clip: float = LLR_CLIP):
         assert chunk_frames > 0
         self.spec = spec
         self.beta = beta
         self.chunk_frames = chunk_frames
-        self.rate = rate
+        self.rate = check_rate(rate, beta)       # None: unpunctured 1/beta
         self.sanitize = sanitize
         self.llr_clip = llr_clip
         self.reset()
@@ -182,16 +183,23 @@ class StreamContext:
         self.n_out = 0                          # bits covered by windows
         self.n_sanitized = 0                    # poisoned values scrubbed
 
+    @functools.cached_property
+    def punctured(self) -> bool:
+        """Whether pushes are a raw punctured stream (depunctured here)
+        rather than whole stages of beta soft symbols (read on every
+        push; rate and beta never change)."""
+        return self.rate != unpunctured(self.beta)
+
     def check_shape(self, llr: np.ndarray) -> None:
         """Reject structurally invalid pushes with a clear error (the raw
         reshape inside ``append`` would raise something cryptic)."""
         if llr.ndim > 2:
             raise ValueError(
                 f"push must be flat or (m, beta); got shape {llr.shape}")
-        if self.rate == "1/2" and llr.size % self.beta != 0:
+        if not self.punctured and llr.size % self.beta != 0:
             raise ValueError(
-                f"rate-1/2 push of {llr.size} values is not a multiple of "
-                f"beta={self.beta} soft symbols per stage")
+                f"rate-{self.rate} push of {llr.size} values is not a "
+                f"multiple of beta={self.beta} soft symbols per stage")
         if llr.ndim == 2 and llr.shape[1] != self.beta:
             raise ValueError(
                 f"2-D push must have beta={self.beta} columns; "
@@ -356,7 +364,7 @@ class StreamContext:
     def append(self, llr) -> int:
         """Absorb raw input; returns the number of stages added.
 
-        rate 1/2: (m, beta) or flat (m*beta,) soft symbols.
+        unpunctured (1/beta): (m, beta) or flat (m*beta,) soft symbols.
         punctured: the raw punctured symbol stream, flat, any slice size —
         the pattern alignment is tracked here, stream-globally."""
         llr = np.asarray(llr, np.float32)
@@ -364,7 +372,7 @@ class StreamContext:
         if self.sanitize != "off":
             llr, n_bad = sanitize_llr(llr, self.llr_clip, self.sanitize)
             self.n_sanitized += n_bad
-        if self.rate != "1/2":
+        if self.punctured:
             return self._stage(llr.reshape(-1), final=False)
         staged = llr.reshape(-1, self.beta)
         if staged.size:
@@ -377,7 +385,7 @@ class StreamContext:
         punctured-rate phase and raw remainder (the serve layer's
         backpressure check runs BEFORE absorbing anything)."""
         llr = np.asarray(llr)
-        if self.rate == "1/2":
+        if not self.punctured:
             return llr.size // self.beta
         return self._complete_stages(self._raw.shape[0] + llr.size)
 
@@ -403,7 +411,7 @@ class StreamContext:
     def _stage_raw_tail(self):
         """Flush-time prelude: convert any leftover raw punctured symbols
         (including a partly-filled final stage) into buffered stages."""
-        if self.rate != "1/2" and self._raw.size:
+        if self.punctured and self._raw.size:
             self._stage(np.zeros((0,), np.float32), final=True)
 
     def flush_window(self) -> Window | None:
@@ -612,7 +620,7 @@ def stream_decode(cfg: DecoderConfig, llr, n: int | None = None, *,
     stream (and needs ``n``); it is depunctured in-stream by the decoder's
     StreamContext (push_size then counts raw symbols)."""
     llr = np.asarray(llr, np.float32)
-    if cfg.rate != "1/2":
+    if cfg.punctured:
         if n is None:
             raise ValueError("n is required for punctured rates")
         llr = llr.reshape(-1)                    # raw punctured symbols

@@ -45,8 +45,9 @@ from .tunedb import TUNE_DB, TuneDB, platform_id
 
 __all__ = ["TilePlan", "DecodePlan", "mosaic_padded_bytes", "tile_ok",
            "unified_vmem_bytes", "split_vmem_bytes", "plan_tiles",
-           "plan_decode", "measure_plan", "DEFAULT_VMEM_BUDGET",
-           "CANDIDATE_TILES", "MAX_FRAMES_PER_TILE", "LANES", "lane_chunks"]
+           "launch_tile", "plan_decode", "measure_plan",
+           "DEFAULT_VMEM_BUDGET", "CANDIDATE_TILES", "MAX_FRAMES_PER_TILE",
+           "LANES", "lane_chunks"]
 # (subframe-geometry validation lives on FrameSpec.validate itself)
 
 DEFAULT_VMEM_BUDGET = 2 * 1024 * 1024          # bytes, per grid step
@@ -292,6 +293,30 @@ def plan_tiles(trellis: Trellis, spec: FrameSpec, *,
     return best
 
 
+def launch_tile(trellis: Trellis, spec: FrameSpec, frames: int | None, *,
+                frames_per_tile: int | str = "auto", unified: bool = True,
+                pack_survivors: bool = True, radix: int = 4,
+                layout=Layout.SUBLANE, bm_dtype: str = "float32",
+                vmem_budget: int = DEFAULT_VMEM_BUDGET) -> TilePlan:
+    """The tile a kernel launch of ``frames`` frames runs: under
+    ``"auto"`` the planner's choice for that many frames
+    (``plan_tiles``), else the pinned ``frames_per_tile`` with its
+    footprint."""
+    if frames_per_tile == "auto":
+        return plan_tiles(trellis, spec, pack_survivors=pack_survivors,
+                          radix=radix, vmem_budget=vmem_budget,
+                          max_frames=frames, unified=unified, layout=layout,
+                          bm_dtype=bm_dtype)
+    layout, mosaic = _resolve(layout, None)
+    model = unified_vmem_bytes if unified else split_vmem_bytes
+    total, breakdown = model(trellis, spec, frames_per_tile,
+                             pack_survivors=pack_survivors, radix=radix,
+                             layout=layout, bm_dtype=bm_dtype, mosaic=mosaic)
+    return TilePlan(int(frames_per_tile), total, breakdown, vmem_budget,
+                    "unified" if unified else "split", layout,
+                    str(bm_dtype), mosaic)
+
+
 @dataclasses.dataclass(frozen=True)
 class DecodePlan:
     """The full configuration the decode front-end executes: kernel knobs
@@ -511,16 +536,11 @@ def plan_decode(trellis: Trellis, spec: FrameSpec, *, unified: bool = True,
         eff_max = (max_frames * bf if (max_frames is not None and bf > 1)
                    else max_frames)
         if frames_per_tile is not None:
-            lay, mosaic = _resolve(
-                _layouts(unified)[-1] if layout == "auto" else layout, None)
-            model = unified_vmem_bytes if unified else split_vmem_bytes
-            total, breakdown = model(
-                trellis, plan_spec, frames_per_tile,
-                pack_survivors=pack_survivors, radix=radix, layout=lay,
-                bm_dtype=bm_dtype, mosaic=mosaic)
-            tile = TilePlan(int(frames_per_tile), total, breakdown,
-                            vmem_budget, "unified" if unified else "split",
-                            lay, str(bm_dtype), mosaic)
+            tile = launch_tile(
+                trellis, plan_spec, None, frames_per_tile=frames_per_tile,
+                unified=unified, pack_survivors=pack_survivors, radix=radix,
+                layout=_layouts(unified)[-1] if layout == "auto" else layout,
+                bm_dtype=bm_dtype, vmem_budget=vmem_budget)
         elif layout == "auto":
             plans = [plan_tiles(trellis, plan_spec,
                                 pack_survivors=pack_survivors,

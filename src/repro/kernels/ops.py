@@ -140,7 +140,7 @@ def viterbi_decode_frames(frames: jax.Array, trellis: Trellis,
     # which knobs?".
     trace = get_tracer()
     trace.event("kernel_trace", kernel="unified" if unified else "split",
-                frames=int(frames.shape[0]),
+                states=trellis.num_states, frames=int(frames.shape[0]),
                 frames_per_tile=int(frames_per_tile), layout=lay.value,
                 bm_dtype=str(bm_dtype), radix=int(radix),
                 pack_survivors=bool(pack_survivors),

@@ -44,7 +44,31 @@ from ..core.framed import flatten_bits
 from ..core.pipeline import DecoderConfig, _build_frame_decoder
 from ..obs.tracer import get_tracer
 
-__all__ = ["PlanCache", "PLAN_CACHE", "build_window_fn"]
+__all__ = ["PlanCache", "PLAN_CACHE", "build_window_fn", "plan_attrs"]
+
+
+def plan_attrs(cfg: DecoderConfig, nframes: int | None = None,
+               mesh=None) -> dict:
+    """What a plan of ``cfg`` chose, as span and snapshot attributes: the
+    trellis's ``states`` and ``beta`` and, for a kernel backend launching
+    ``nframes`` frames (over ``mesh``'s devices), the kernel tile's
+    ``frames_per_tile`` and its planned VMEM bytes a grid step."""
+    from ..kernels.autotune import launch_tile
+    from ..kernels.block import resolve_block
+    tr = cfg.trellis
+    attrs = {"states": tr.num_states, "beta": tr.beta}
+    if cfg.backend == "reference" or nframes is None:
+        return attrs
+    bf, ov = resolve_block(tr, cfg.spec, cfg.block_frames, cfg.overlap)
+    ndev = int(mesh.devices.size) if mesh is not None else 1
+    tile = launch_tile(
+        tr, cfg.spec.blocked(bf, ov) if bf > 1 else cfg.spec,
+        -(-int(nframes) // ndev) * bf, frames_per_tile=cfg.frames_per_tile,
+        unified=cfg.backend == "kernel", pack_survivors=cfg.pack_survivors,
+        radix=cfg.radix, layout=cfg.layout, bm_dtype=cfg.bm_dtype)
+    attrs.update(frames_per_tile=tile.frames_per_tile,
+                 vmem_bytes=tile.vmem_bytes)
+    return attrs
 
 
 def build_window_fn(spec, decode_frames, nframes: int, trace_hook=None):
@@ -82,12 +106,13 @@ class PlanCache:
         self.build_ms = 0.0
 
     # -- bookkeeping ------------------------------------------------------
-    def _get(self, key, build, refresh: bool = False):
+    def _get(self, key, build, plan: tuple, refresh: bool = False):
         """Cached build. ``refresh=True`` drops any existing entry first —
         the fault-injection harness uses it to force the cold path (an
         evicted / never-compiled plan) on a live server. Misses time the
-        build under a ``plan_build`` span; ``stats()`` counts hits, misses
-        and traces."""
+        build under a ``plan_build`` span, which a recording tracer gets
+        with ``plan_attrs(*plan)``; ``stats()`` counts hits, misses and
+        traces."""
         with self._lock:
             if refresh:
                 self._fns.pop(key, None)
@@ -97,7 +122,9 @@ class PlanCache:
                 return fn
             self.misses += 1
         t0 = time.perf_counter()
-        with get_tracer().span("plan_build", kind=str(key[0])):
+        tracer = get_tracer()
+        attrs = plan_attrs(*plan) if tracer.enabled else {}
+        with tracer.span("plan_build", kind=str(key[0]), **attrs):
             fn = build()                        # build outside the lock
         dt_ms = (time.perf_counter() - t0) * 1e3
         with self._lock:
@@ -128,13 +155,14 @@ class PlanCache:
         frame axis is sharded across the mesh devices
         (distributed/stream.py)."""
         if mesh is None:
-            return self._get(("frames", cfg), lambda: _build_frame_decoder(cfg))
+            return self._get(("frames", cfg),
+                             lambda: _build_frame_decoder(cfg), (cfg,))
 
         def build():
             from ..distributed.stream import make_sharded_frame_decoder
             return make_sharded_frame_decoder(cfg, mesh)
 
-        return self._get(("frames", cfg, mesh), build)
+        return self._get(("frames", cfg, mesh), build, (cfg, None, mesh))
 
     def window_decoder(self, cfg: DecoderConfig, nframes: int, *, mesh=None):
         """Jitted chunk-window decoder (stream layer). Callers with a
@@ -144,7 +172,7 @@ class PlanCache:
         key = ("window", cfg, int(nframes), mesh)
         return self._get(key, lambda: build_window_fn(
             cfg.spec, self.frame_decoder(cfg, mesh), int(nframes),
-            self._mark_trace))
+            self._mark_trace), key[1:])
 
     def batch_decoder(self, cfg: DecoderConfig, nframes: int, *, mesh=None,
                       refresh: bool = False):
@@ -166,7 +194,7 @@ class PlanCache:
 
             return run
 
-        return self._get(key, build, refresh=refresh)
+        return self._get(key, build, key[1:], refresh=refresh)
 
 
 #: Process-global cache: tenant churn anywhere in the process never

@@ -218,9 +218,10 @@ class Bucket:
         self.key = key
         self.plan = plan
         self.chunk_frames = plan.chunk_frames
-        # the decode identity strips the rate: depuncture is per-session
-        # upstream, so every rate shares this bucket's compiled decoders
-        self.decode_cfg = dataclasses.replace(cfg, rate="1/2")
+        # the decode identity strips the rate to the trellis's own 1/beta:
+        # depuncture is per-session upstream, so every rate of this
+        # trellis shares this bucket's compiled decoders
+        self.decode_cfg = dataclasses.replace(cfg, rate=None)
         self.sessions: set[int] = set()
         self.queue: collections.deque[PendingWindow] = collections.deque()
         self.inflight: collections.deque = collections.deque()  # launches

@@ -105,7 +105,7 @@ from ..core.sanitize import LLR_CLIP, sanitize_llr
 from ..core.stream import StreamContext
 from ..obs.tracer import get_tracer
 from .metrics import ServeMetrics
-from .plan_cache import PLAN_CACHE, PlanCache
+from .plan_cache import PLAN_CACHE, PlanCache, plan_attrs
 from .scheduler import Breaker, Bucket, Session, bucket_plan
 
 __all__ = ["DecodeServer", "ServeError", "ServerFull", "Backpressure",
@@ -839,15 +839,22 @@ class DecodeServer:
         sessions; ``breakers`` carries every primary bucket's circuit
         breaker (state/trips/consecutive); ``checkpoint`` the save/
         restore counts; ``faults`` reports the injector's schedule
-        counters when one is attached. ``stages_hist`` carries the same
-        stage histograms at full bucket resolution (Prometheus histogram
-        shape — cumulative ``[le, count]`` pairs), so a scrape exports
-        aggregatable ``_bucket`` series, not just point summaries."""
+        counters when one is attached; ``plans`` what each bucket's plan
+        chose for a full launch (``plan_cache.plan_attrs``: states, beta,
+        frames per tile, planned VMEM bytes). ``stages_hist`` carries the
+        same stage histograms at full bucket resolution (Prometheus
+        histogram shape — cumulative ``[le, count]`` pairs), so a scrape
+        exports aggregatable ``_bucket`` series, not just point
+        summaries."""
         snap = {"buckets": self.metrics.snapshot(),
                 "totals": self.metrics.totals(),
                 "stages": self.metrics.stage_snapshot(),
                 "stages_hist": self.metrics.stage_histograms(),
                 "plan_cache": self.cache.stats(),
+                "plans": {b.id: plan_attrs(b.decode_cfg,
+                                           self.slots * b.chunk_frames,
+                                           b.mesh)
+                          for b in self._buckets.values()},
                 "sessions": len(self._sessions),
                 "quarantined_sessions": sum(
                     1 for s in self._sessions.values()

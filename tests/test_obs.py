@@ -545,3 +545,48 @@ def test_record_fault_rejects_unknown_counter():
         m.record_fault("not_a_counter")
     m.record_fault("retries", error="e1", n=2)
     assert m.retries == 2 and m.last_error == "e1"
+
+
+def _kernel_server_k5():
+    from repro.core import DecoderConfig, FrameSpec, make_trellis
+    from repro.serve import DecodeServer, PlanCache
+    spec = FrameSpec(f=64, v1=16, v2=20, f0=16, v2s=20)
+    cfg = DecoderConfig(trellis=make_trellis(5, (0o23, 0o35)), spec=spec,
+                        backend="kernel", interpret=True, layout="sublane")
+    srv = DecodeServer(slots=2, cache=PlanCache())
+    sid = srv.open_session(cfg, chunk_frames=2)
+    rx = np.random.default_rng(0).standard_normal((5 * 64, 2))
+    srv.push(sid, rx.astype(np.float32))
+    srv.drain()
+    return srv
+
+
+def test_plan_build_and_snapshot_name_what_the_plan_chose():
+    """Under a recording tracer the ``plan_build`` span of a kernel plan
+    carries its states, beta, frame tile and planned VMEM bytes; the
+    server's snapshot lists the same per bucket, and ``kernel_trace``
+    names the states."""
+    t = Tracer()
+    set_tracer(t)
+    srv = _kernel_server_k5()
+    builds = [r.attrs for r in t.spans() if r.name == "plan_build"]
+    batch = [a for a in builds if a["kind"] == "batch"]
+    assert batch and all(a["states"] == 16 and a["beta"] == 2
+                         for a in builds)
+    assert all(a["frames_per_tile"] >= 4 and a["vmem_bytes"] > 0
+               for a in batch)
+    (plan,) = srv.metrics_snapshot()["plans"].values()
+    assert plan == {k: batch[0][k] for k in
+                    ("states", "beta", "frames_per_tile", "vmem_bytes")}
+    traces = [r.attrs for r in t.spans() if r.name == "kernel_trace"]
+    assert traces and all(a["states"] == 16 for a in traces)
+
+
+def test_plan_build_attributes_cost_nothing_untraced(monkeypatch):
+    """With no tracer recording, a plan build never computes them."""
+    import repro.serve.plan_cache as plan_cache
+
+    def refuse(*a, **k):
+        raise AssertionError("plan attributes computed untraced")
+    monkeypatch.setattr(plan_cache, "plan_attrs", refuse)
+    _kernel_server_k5()

@@ -4,7 +4,10 @@ chip. Covers the programs chip_smoke.py runs — the unified kernel at
 phase A's shape, phase B's serve-bucket batch decoders at their padded
 batch, phase C's blocked and sequential long-frame specs — plus the
 non-default kernel knobs that compile (radix 2, unpacked survivors), and
-phase D's frame-sharded batch decoder on the described 2x2 host.
+phase D's frame-sharded batch decoder on the described 2x2 host; and the
+benchmark's launches that chip_smoke.py does not make: the K=9 rate-1/3
+UMTS bucket (256 states, three generators) at its 1,024-frame launch and
+the DVB-S bucket's 4,096-frame launch sharded over the 2x2 host.
 
 The topology is described inside a fixture, never while a module is
 imported (only one process may load the TPU library at a time), and the
@@ -29,8 +32,11 @@ SPEC_A = FrameSpec(f=256, v1=20, v2=45, f0=32, v2s=45)
 SPEC_12 = FrameSpec(f=64, v1=16, v2=20, f0=16, v2s=20)
 SPEC_34 = FrameSpec(f=63, v1=21, v2=21, f0=21, v2s=21)     # L=105, odd
 SPEC_C = FrameSpec(f=4096, v1=32, v2=32, f0=32, v2s=32)
+SPEC_UMTS = FrameSpec(f=268, v1=36, v2=54, f0=67, v2s=54)
+SPEC_DVBS = FrameSpec(f=288, v1=21, v2=45, f0=32, v2s=45)
 K5 = (5, (0o23, 0o35))
 K7 = (7, (0o171, 0o133))
+K9 = (9, (0o557, 0o663, 0o711))
 
 
 @pytest.fixture(scope="module")
@@ -79,16 +85,19 @@ def test_unified_kernel_phase_a(one_chip, frames, knobs):
     _compile(fn, (frames, SPEC_A.frame_len, 2), one_chip)
 
 
-@pytest.mark.parametrize("code,spec", [(K7, SPEC_12), (K7, SPEC_34),
-                                       (K5, SPEC_12)],
-                         ids=["K7-f64", "K7-f63-oddL", "K5-f64"])
-def test_serve_bucket_batch_decoder(one_chip, code, spec):
+@pytest.mark.parametrize("code,spec,frames", [
+    (K7, SPEC_12, 16 * 16), (K7, SPEC_34, 16 * 16), (K5, SPEC_12, 16 * 16),
+    (K9, SPEC_UMTS, 8 * 128)],
+    ids=["K7-f64", "K7-f63-oddL", "K5-f64", "K9-r13-f268"])
+def test_serve_bucket_batch_decoder(one_chip, code, spec, frames):
     """The jitted program one serve bucket launches: slots=16 windows of
-    16 frames, as phase B batches them."""
-    cfg = DecoderConfig(trellis=make_trellis(*code), spec=spec,
-                        backend="kernel", interpret=False, layout="sublane")
-    fn = PlanCache().batch_decoder(cfg, 16 * 16)
-    _compile(fn, (16 * 16, spec.frame_len, 2), one_chip)
+    16 frames, as phase B batches them; the UMTS cell's 8 windows of 128
+    blocks of the K=9 rate-1/3 code."""
+    trellis = make_trellis(*code)
+    cfg = DecoderConfig(trellis=trellis, spec=spec, backend="kernel",
+                        interpret=False, layout="sublane")
+    fn = PlanCache().batch_decoder(cfg, frames)
+    _compile(fn, (frames, spec.frame_len, trellis.beta), one_chip)
 
 
 @pytest.mark.parametrize("blocked", [True, False],
@@ -102,16 +111,20 @@ def test_long_frame_phase_c(one_chip, blocked):
     _compile(fn, (8, SPEC_C.frame_len, 2), one_chip)
 
 
-def test_sharded_serve_batch_four_chips(topo):
-    """Phase D's program: a serve bucket's batch decoder on a 4-chip
-    'frames' mesh keeps the batch sharded over all four chips."""
+@pytest.mark.parametrize("spec,frames", [(SPEC_12, 16 * 16),
+                                         (SPEC_DVBS, 32 * 128)],
+                         ids=["phase-d", "dvbs-bulk32"])
+def test_sharded_serve_batch_four_chips(topo, spec, frames):
+    """Phase D's program, and the DVB-S four-chip cell's 32 windows of
+    128 frames: a serve bucket's batch decoder on a 4-chip 'frames' mesh
+    keeps the batch sharded over all four chips."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     mesh = Mesh(np.array(topo.devices), ("frames",))
-    cfg = DecoderConfig(spec=SPEC_12, backend="kernel", interpret=False,
+    cfg = DecoderConfig(spec=spec, backend="kernel", interpret=False,
                         layout="sublane")
-    fn = PlanCache().batch_decoder(cfg, 16 * 16, mesh=mesh)
-    compiled = _compile(fn, (16 * 16, SPEC_12.frame_len, 2),
+    fn = PlanCache().batch_decoder(cfg, frames, mesh=mesh)
+    compiled = _compile(fn, (frames, spec.frame_len, 2),
                         NamedSharding(mesh, P("frames")))
     assert compiled.output_shardings.spec == P("frames")
     assert compiled.output_shardings.mesh.devices.size == 4
