@@ -219,7 +219,9 @@ class DecodeServer:
                   ``launch_attempt``/``degrade``), ``retire`` (around
                   ``retire_wait``), ``evacuate``, ``readmit``,
                   ``breaker_probe``; async ``inflight``; instants
-                  ``retry``, ``breaker_open``, ``breaker_close``. None
+                  ``retry``, ``breaker_open``, ``breaker_close``; counter
+                  ``d2h_prefetch`` (launches whose copy back started at
+                  dispatch). None
                   (default) resolves to the process-global tracer — a
                   pay-nothing no-op unless ``repro.obs.set_tracer``
                   installed one.
@@ -568,6 +570,23 @@ class DecodeServer:
             primary.queue.extend(bucket.queue)
             bucket.queue.clear()
 
+    def _inflight(self, bucket: Bucket, out, taken, batch, **attrs) -> None:
+        """Put one launch in flight with its device-to-host copy already
+        started: the copy queues behind the kernel and lands while the
+        host stages the next round, so ``_retire``'s ``np.asarray`` finds
+        the bits on the host. A copy that cannot start is no launch
+        failure (no retry, no fault): its error surfaces in ``_retire``'s
+        materialization, whose degrade path re-decodes the batch."""
+        try:
+            out.copy_to_host_async()
+            self.trace.count("d2h_prefetch")
+        except RuntimeError:
+            pass
+        bucket.inflight.append(
+            (out, taken, batch,
+             self.trace.begin("inflight", bucket=bucket.id,
+                              frames=batch.shape[0], **attrs)))
+
     def _probe(self, primary: Bucket, bucket: Bucket, dev, batch, taken,
                B: int) -> bool:
         """Half-open probe: try this failover batch on the primary's
@@ -589,10 +608,7 @@ class DecodeServer:
                 self.trace.event("breaker_open", bucket=primary.id,
                                  probe_failed=True)
             return False
-        bucket.inflight.append(
-            (out, taken, batch,
-             self.trace.begin("inflight", bucket=bucket.id, frames=B,
-                              probe=True)))
+        self._inflight(bucket, out, taken, batch, probe=True)
         if primary.breaker.record_success():          # half_open -> closed
             self.trace.event("breaker_close", bucket=primary.id)
         self._readmit(bucket, primary)
@@ -621,10 +637,7 @@ class DecodeServer:
                                  pinned=True):
                 out = self.cache.batch_decoder(bucket.decode_cfg, B,
                                                mesh=bucket.mesh)(dev)
-            bucket.inflight.append(
-                (out, taken, batch,
-                 self.trace.begin("inflight", bucket=bucket.id, frames=B,
-                                  pinned=True)))
+            self._inflight(bucket, out, taken, batch, pinned=True)
             return
         deadline = self.launch_timeout_s
         tripped = False
@@ -648,10 +661,7 @@ class DecodeServer:
                         raise LaunchTimeout(
                             f"bucket {bucket.id}: launch exceeded "
                             f"{deadline * 1e3:.1f} ms deadline")
-                bucket.inflight.append(
-                    (out, taken, batch,
-                     self.trace.begin("inflight", bucket=bucket.id,
-                                      frames=B)))
+                self._inflight(bucket, out, taken, batch)
                 if bucket.breaker.state != "open":
                     # a late success after the breaker tripped mid-retry
                     # must NOT reset `consecutive`: the breaker stays
@@ -687,10 +697,7 @@ class DecodeServer:
         bm.record_fault("degraded")
         with self.trace.span("degrade", bucket=bucket.id, frames=B):
             out = self._ref_fallback(bucket, B)(dev)
-        bucket.inflight.append(
-            (out, taken, batch,
-             self.trace.begin("inflight", bucket=bucket.id, frames=B,
-                              degraded=True)))
+        self._inflight(bucket, out, taken, batch, degraded=True)
         if tripped or bucket.breaker.state != "closed":
             self._evacuate(bucket)
 
