@@ -1,8 +1,7 @@
-"""Benchmark driver — one section per paper table/figure + model zoo.
+"""Benchmark driver — one section per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV rows (derived = the table's natural
-metric: Mb/s for throughput tables, dB-to-theory for BER tables,
-tokens/s for the model zoo).
+metric: Mb/s for throughput tables, dB-to-theory for BER tables).
 
   PYTHONPATH=src python -m benchmarks.run [--full] [--only SECTION]
 
@@ -20,10 +19,10 @@ def main() -> None:
     ap.add_argument("--full", action="store_true",
                     help="paper-sized grids (slow)")
     ap.add_argument("--only", default=None,
-                    choices=["throughput", "kernels", "ber", "models"])
+                    choices=["throughput", "kernels", "ber"])
     args = ap.parse_args()
 
-    from . import ber_tables, models_bench, throughput
+    from . import ber_tables, throughput
 
     print("name,us_per_call,derived")
     if args.only in (None, "kernels"):
@@ -31,9 +30,7 @@ def main() -> None:
         rows = throughput.kernel_sweep(full=args.full)
         for r in rows:
             name = (f"kern_pack{int(r['pack'])}_radix{r['radix']}_"
-                    f"ft{r['ft']}_{r['layout']}"
-                    + ("_bf16" if r["bm_dtype"] == "bfloat16" else "")
-                    + ("_auto" if r["auto"] else ""))
+                    f"ft{r['ft']}" + ("_auto" if r["auto"] else ""))
             print(f"{name},{r['us_per_call']:.1f},{r['mbps']:.2f}Mbps")
         stream_rows = throughput.streaming_bench(full=args.full)
         for r in stream_rows:
@@ -65,10 +62,6 @@ def main() -> None:
                 f"{k}{v}" for k, v in r.items()
                 if k in ("f", "v2", "f0", "start"))
             print(f"{name},0,{r['dist_db']:.3f}dB")
-    if args.only in (None, "models"):
-        for r in models_bench.main():
-            print(f"model_{r['arch']},{r['us_per_call']:.0f},"
-                  f"{r['tokens_per_s']:.0f}tok/s")
 
 
 if __name__ == "__main__":

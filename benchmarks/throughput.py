@@ -1,10 +1,9 @@
 """Paper Tables IV & V: decoder throughput, regular vs parallel traceback.
 
-The container has no GPU/TPU; wall-clock numbers are CPU (jitted XLA) and
-meaningful as RELATIVE comparisons between the paper's own variants:
-  * serial vs parallel traceback        (Table IV vs V: paper sees ~2x)
-  * unified vs split (global-memory) survivor-path storage (Table I)
-The TPU-side absolute projection comes from the §Roofline analysis instead.
+Without an accelerator the wall-clock numbers are CPU (jitted XLA) and
+meaningful only as RELATIVE comparisons between the paper's own variants:
+serial vs parallel traceback (Table IV vs V: paper sees ~2x). Chip
+numbers come from ``bench/run.py``.
 """
 from __future__ import annotations
 
@@ -86,33 +85,14 @@ def table5(n=1_000_000):
     return rows
 
 
-def unified_vs_split(n=80_000):
-    """Table I comparison on the kernel path (interpret mode => relative)."""
-    rng = np.random.default_rng(0)
-    spec = FrameSpec(f=256, v1=20, v2=45, f0=32, v2s=45)
-    llr = jnp.asarray(rng.standard_normal((n, 2)).astype(np.float32))
-    frames = frame_llr(llr, spec)
-    rows = []
-    # the split kernel runs in interpret mode only
-    for unified in ((True,) if COMPILED else (True, False)):
-        fn = jax.jit(lambda fr: ops.viterbi_decode_frames(
-            fr, STD_K7, spec, unified=unified, interpret=_interpret()))
-        dt = _time(fn, frames, reps=1)
-        rows.append({"table": "I", "variant": "unified" if unified else "split",
-                     "us_per_call": dt * 1e6, "mbps": n / dt / 1e6})
-    return rows
-
-
 def kernel_sweep(full: bool = False):
-    """Packed x radix x tile-size x layout x bm-dtype sweep.
+    """Packed x radix x tile-size sweep of the unified kernel.
 
-    The perf-trajectory benchmark for the unified kernel's survivor
-    compression (BENCH_kernels.json). The (pack=False, radix=2, ft=8) row
-    is the seed kernel; (pack=True, radix=4, ft>=32) is PR-1's optimized
-    configuration; the 'sublane' rows are the Mosaic-native layout whose
-    packing survives hardware lane padding (their vmem_mosaic_kib column
-    is the honest hardware footprint — compare it with the lane rows').
-    Interpret mode => relative numbers.
+    The perf-trajectory benchmark for the kernel's survivor compression
+    (BENCH_kernels.json). The (pack=False, radix=2, ft=8) row is the seed
+    kernel; (pack=True, radix=4, ft='auto') is the served configuration.
+    ``vmem_kib`` is the tile's footprint under Mosaic padding. Interpret
+    mode => relative numbers.
     """
     rng = np.random.default_rng(0)
     spec = FrameSpec(f=256, v1=20, v2=45, f0=32, v2s=45)
@@ -120,50 +100,31 @@ def kernel_sweep(full: bool = False):
     llr = jnp.asarray(rng.standard_normal((n, 2)).astype(np.float32))
     frames = frame_llr(llr, spec)
 
-    from repro.kernels.autotune import plan_tiles, unified_vmem_bytes
-    grid = [(False, 2, 8, "lane", "float32"),            # seed configuration
-            (False, 4, 8, "lane", "float32"),            # one knob at a time
-            (True, 2, 8, "lane", "float32"),
-            (True, 4, 8, "lane", "float32"),
-            (False, 2, 32, "lane", "float32"),           # deeper tiles
-            (True, 4, 32, "lane", "float32"),
-            (True, 4, "auto", "lane", "float32"),        # PR-1 autotuned
-            (True, 4, 8, "sublane", "float32"),          # Mosaic-native
-            (True, 4, 32, "sublane", "float32"),
-            (True, 4, "auto", "sublane", "float32"),
-            (True, 4, 32, "sublane", "bfloat16")]        # compressed BMs
+    from repro.kernels.autotune import launch_tile, tile_ok
+    grid = [(False, 2, 8),                          # seed configuration
+            (False, 4, 8),                          # one knob at a time
+            (True, 2, 8),
+            (True, 4, 8),
+            (False, 2, 32),                         # deeper tiles
+            (True, 4, 32),
+            (True, 4, "auto")]                      # the served plan
     if COMPILED:
-        # the rows the chip can run: the sublane layout, float32 metrics,
         # tiles Mosaic accepts (kernels/viterbi_unified.check_compiled)
-        from repro.kernels.autotune import tile_ok
-        grid = [g for g in grid if g[3] == "sublane" and g[4] == "float32"
-                and (g[2] == "auto"
-                     or tile_ok(g[3], g[2], frames.shape[0]))]
+        grid = [g for g in grid
+                if g[2] == "auto" or tile_ok(g[2], frames.shape[0])]
     rows = []
-    for pack, radix, ft, layout, bm_dtype in grid:
-        fn = jax.jit(lambda fr, p=pack, r=radix, t=ft, lay=layout,
-                     bd=bm_dtype: ops.viterbi_decode_frames(
+    for pack, radix, ft in grid:
+        fn = jax.jit(lambda fr, p=pack, r=radix, t=ft:
+                     ops.viterbi_decode_frames(
                          fr, STD_K7, spec, frames_per_tile=t,
-                         pack_survivors=p, radix=r, layout=lay, bm_dtype=bd,
-                         interpret=_interpret()))
+                         pack_survivors=p, radix=r, interpret=_interpret()))
         dt = _time_best(fn, frames, reps=3)
-        ft_res = (plan_tiles(STD_K7, spec, pack_survivors=pack, radix=radix,
-                             layout=layout, bm_dtype=bm_dtype,
-                             max_frames=frames.shape[0]).frames_per_tile
-                  if ft == "auto" else ft)
-        vmem, _ = unified_vmem_bytes(STD_K7, spec, ft_res,
-                                     pack_survivors=pack, radix=radix,
-                                     layout=layout, bm_dtype=bm_dtype,
-                                     mosaic=False)
-        vmem_m, _ = unified_vmem_bytes(STD_K7, spec, ft_res,
-                                       pack_survivors=pack, radix=radix,
-                                       layout=layout, bm_dtype=bm_dtype,
-                                       mosaic=True)
+        tile = launch_tile(STD_K7, spec, frames.shape[0],
+                           frames_per_tile=ft, pack_survivors=pack)
         rows.append({"table": "kernels", "pack": pack, "radix": radix,
-                     "ft": ft_res, "auto": ft == "auto", "layout": layout,
-                     "bm_dtype": bm_dtype, "n_bits": n, "reps": 3,
-                     "vmem_kib": round(vmem / 1024, 1),
-                     "vmem_mosaic_kib": round(vmem_m / 1024, 1),
+                     "ft": tile.frames_per_tile, "auto": ft == "auto",
+                     "n_bits": n, "reps": 3,
+                     "vmem_kib": round(tile.vmem_bytes / 1024, 1),
                      "us_per_call": dt * 1e6, "mbps": n / dt / 1e6})
     return rows
 
@@ -445,7 +406,7 @@ def block_bench(full: bool = False):
     by_variant = {}
     for variant, B, o in (("sequential", 1, 0), ("blocked", bf, ov)):
         fn = jax.jit(lambda fr, B=B, o=o: ops.viterbi_decode_frames(
-            fr, STD_K7, spec, frames_per_tile="auto", layout="sublane",
+            fr, STD_K7, spec, frames_per_tile="auto",
             block_frames=B, overlap=o, interpret=_interpret()))
         dt = _time_best(fn, frames, reps=2)
         mbps = n / dt / 1e6
@@ -555,27 +516,15 @@ def serve_load_sweep(full: bool = False):
 
 
 def plan_rows():
-    """Tile plans across layouts/models at the default 2 MiB budget — the
-    BENCH_kernels.json record behind the layout acceptance criterion
-    (sublane-major fits >= 2x the frames per tile of the PR-1 plan under
-    honest hardware accounting)."""
+    """The kernel's tile plans at the default 2 MiB budget, packed and
+    unpacked survivors, under Mosaic padding — the BENCH_kernels.json
+    record of what the planner chooses."""
     from repro.kernels.autotune import plan_tiles
     spec = FrameSpec(f=256, v1=20, v2=45, f0=32, v2s=45)
-    entries = [
-        ("lane_logical_pr1", dict(layout="lane", mosaic=False)),
-        ("lane_mosaic", dict(layout="lane", mosaic=True)),
-        ("sublane_mosaic", dict(layout="sublane")),
-        ("sublane_mosaic_bf16", dict(layout="sublane",
-                                     bm_dtype="bfloat16")),
-        ("split_lane_logical", dict(layout="lane", mosaic=False,
-                                    unified=False)),
-    ]
     rows = []
-    for name, kw in entries:
-        p = plan_tiles(STD_K7, spec, pack_survivors=True, radix=4, **kw)
+    for name, pack in (("packed", True), ("unpacked", False)):
+        p = plan_tiles(STD_K7, spec, pack_survivors=pack)
         rows.append({"table": "plans", "plan": name,
-                     "kernel": p.kernel, "layout": p.layout.value,
-                     "bm_dtype": p.bm_dtype, "mosaic": p.mosaic,
                      "ft": p.frames_per_tile,
                      "vmem_kib": round(p.vmem_bytes / 1024, 1),
                      "budget_kib": round(p.budget / 1024, 1),
@@ -588,7 +537,6 @@ def plan_rows():
 SECTIONS = {
     "table4": lambda full: table4(4_000_000 if full else 1_000_000),
     "table5": lambda full: table5(4_000_000 if full else 1_000_000),
-    "unified_vs_split": lambda full: unified_vs_split(),
     "kernels": kernel_sweep,
     "streaming": streaming_bench,
     "serve": serve_bench,
@@ -599,8 +547,8 @@ SECTIONS = {
 }
 
 #: The historical default — what plain ``python benchmarks/throughput.py``
-#: has always printed (paper Tables IV/V + the Table I comparison).
-DEFAULT_SECTIONS = "table4,table5,unified_vs_split"
+#: has always printed (paper Tables IV/V).
+DEFAULT_SECTIONS = "table4,table5"
 
 #: What ``--compiled`` runs when ``--sections`` is not given: the
 #: trajectory sections whose compiled-mode numbers ROADMAP item 3 wants,
@@ -623,8 +571,7 @@ def _cli(argv=None):
     import argparse
     ap = argparse.ArgumentParser(
         description="decoder throughput benches (paper Tables IV/V, "
-                    "unified-vs-split, kernel sweep, streaming, serve, "
-                    "block-parallel)")
+                    "kernel sweep, streaming, serve, block-parallel)")
     ap.add_argument("--full", action="store_true",
                     help="4M-bit workload instead of the 1M-bit quick run")
     ap.add_argument("--sections", default=DEFAULT_SECTIONS,

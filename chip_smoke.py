@@ -88,10 +88,10 @@ def log(*parts) -> None:
 def kernel_cfg(over: dict, **kw) -> DecoderConfig:
     """A DecoderConfig on the platform defaults, plus ``over`` (the CPU
     rehearsal pins the kernel backend there); it must name the unified
-    kernel in the sublane layout."""
+    kernel."""
     cfg = DecoderConfig(**kw, **over)
-    check(cfg.backend == "kernel" and cfg.layout == "sublane",
-          f"config does not select the sublane kernel: {cfg}")
+    check(cfg.backend == "kernel",
+          f"config does not select the kernel: {cfg}")
     return cfg
 
 

@@ -3,15 +3,10 @@
 PYTHONPATH=src python examples/quickstart.py
 
 DecoderConfig knobs beyond the defaults shown here:
-  * layout='sublane'     — the survivor layout Mosaic compiles (frames on
-    the 128 TPU lanes, flat stage-major scratches): bit-identical, and
-    the default on a TPU; 'lane' runs in interpret mode only.
-  * bm_dtype='bfloat16'  — store the eq.-9 branch metrics compressed
-    (fp32 path-metric accumulation), interpret mode only. Halves the
-    second-largest VMEM term; BER within 1e-3 of float32 at Eb/N0 >= 2 dB
-    (tests/test_ber.py).
-  * frames_per_tile='auto' (default) budgets whichever kernel/layout/
-    dtype combination actually runs (kernels/autotune.plan_tiles).
+  * pack_survivors / radix — bit-packed survivors and two trellis stages
+    per scan step (the defaults); every combination is bit-identical.
+  * frames_per_tile='auto' (default) sizes the kernel's frame tile from
+    its VMEM footprint (kernels/autotune.plan_tiles).
 
 For unbounded inputs, use the STREAMING front-end instead of one shot:
 

@@ -52,7 +52,6 @@ Block-parallel decode (PR 9):
   PYTHONPATH=src python examples/serve_viterbi.py --sessions 2 \\
       --chunks 2 --chunk-frames 2 --block-frames auto
 
-(For the unrelated LM continuous-batching demo, see examples/serve_lm.py.)
 """
 import argparse
 import os

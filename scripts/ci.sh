@@ -62,11 +62,10 @@ def bench(label, **kw):
     print(f"smoke {label}: {dt*1e3:.1f} ms  (bit-exact)")
     return dt
 
-seed = bench("seed    (unpacked, radix-2, ft=8, lane)",
-             pack_survivors=False, radix=2, frames_per_tile=8, layout="lane")
-opt = bench("optimized (packed, radix-4, auto, sublane)",
-            pack_survivors=True, radix=4, frames_per_tile="auto",
-            layout="sublane")
+seed = bench("seed    (unpacked, radix-2, ft=8)",
+             pack_survivors=False, radix=2, frames_per_tile=8)
+opt = bench("optimized (packed, radix-4, auto)",
+            pack_survivors=True, radix=4, frames_per_tile="auto")
 # bit-exactness above is the hard gate; shared-runner wall clock is too
 # noisy (seed config varies ~1.7x run-to-run) for a tight perf assert, so
 # only fail on a gross regression and warn otherwise.

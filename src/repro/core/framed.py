@@ -5,7 +5,7 @@ stages [m*f, (m+1)*f) but *processes* stages [m*f - v1, m*f + f + v2): the
 left overlap v1 warms up the path metrics, the right overlap v2 lets the
 survivor path converge before the kept region (paper Fig. 2b). Frames are
 embarrassingly parallel: vmap here, grid axis in the Pallas kernel, and the
-sharded axis in the multi-pod launch.
+sharded axis of the frame mesh (distributed/stream.py).
 """
 from __future__ import annotations
 

@@ -25,14 +25,15 @@ __all__ = ["DecoderConfig", "make_decoder", "make_frame_decoder",
 
 
 def platform_defaults() -> dict:
-    """``backend``, ``interpret`` and ``layout`` where the caller leaves
-    them (DecoderConfig, ops.viterbi_decode_frames), from
-    ``jax.default_backend()``: on a TPU the compiled unified kernel in the
-    sublane layout; elsewhere the pure-JAX reference, with the Pallas
-    kernels, when asked for, interpreted in the lane layout."""
+    """``backend`` and ``interpret`` where the caller leaves them
+    (DecoderConfig, ops.viterbi_decode_frames), from
+    ``jax.default_backend()``: on a TPU the compiled unified kernel;
+    elsewhere the pure-JAX reference, with the kernel, when asked for,
+    interpreted — the same program Mosaic compiles. Callers read only
+    the keys they need, so a dict with more keys is fine."""
     if jax.default_backend() == "tpu":
-        return {"backend": "kernel", "interpret": False, "layout": "sublane"}
-    return {"backend": "reference", "interpret": True, "layout": "lane"}
+        return {"backend": "kernel", "interpret": False}
+    return {"backend": "reference", "interpret": True}
 
 
 def _platform(knob: str):
@@ -50,14 +51,8 @@ class DecoderConfig:
     (set radix=2, pack_survivors=False, frames_per_tile=8 for the seed
     kernel behavior).
 
-    ``backend``, ``interpret`` and ``layout`` default from the platform
-    when the config is built (``platform_defaults``). ``layout`` picks the
-    survivor-memory orientation ('lane' = frames on sublanes, interpret
-    mode only; 'sublane' = frames on lanes, the layout Mosaic compiles) —
-    still bit-exact. ``bm_dtype='bfloat16'`` stores branch metrics
-    compressed with float32 path-metric accumulation: the one knob that is
-    NOT bit-exact, but BER-neutral to within 1e-3 at Eb/N0 >= 2 dB
-    (tests/test_ber.py gates it).
+    ``backend`` ('reference' or 'kernel') and ``interpret`` default from
+    the platform when the config is built (``platform_defaults``).
 
     ``rate`` names the puncturing pattern over the trellis's beta
     generators; None (the default) is the unpunctured ``1/beta`` —
@@ -76,21 +71,19 @@ class DecoderConfig:
     overlap-stage training/truncation region on each side, decoded in
     parallel and merged by truncation. ``"auto"`` engages blocking only
     past BLOCK_LEN_THRESHOLD kept stages; ``overlap=None`` takes the
-    ~5*constraint-length default. The second knob besides bf16 that is
-    not bit-exact (truncated-traceback approximation, BER-gated to 1e-3
-    in tests/test_block.py) — applied by ALL backends, reference
-    included, so kernel-vs-reference stays bit-identical under blocking.
+    ~5*constraint-length default. The one knob that is not bit-exact
+    (truncated-traceback approximation, BER-gated to 1e-3 in
+    tests/test_block.py) — applied by both backends, reference included,
+    so kernel-vs-reference stays bit-identical under blocking.
     """
     trellis: Trellis = STD_K7
     spec: FrameSpec = FrameSpec()
     rate: str | None = None        # puncturing pattern; None = 1/beta
-    backend: str = _platform("backend")   # 'reference'|'kernel'|'kernel_split'
+    backend: str = _platform("backend")   # 'reference' | 'kernel'
     interpret: bool = _platform("interpret")   # Pallas interpret mode
-    pack_survivors: bool = True    # bit-pack survivors 32x (kernel backends)
+    pack_survivors: bool = True    # bit-pack survivors 32x (kernel)
     radix: int = 4                 # 2 | 4 trellis stages per ACS step
     frames_per_tile: int | str = "auto"   # tile size, or VMEM-planned
-    layout: str = _platform("layout")     # 'lane' | 'sublane' survivors
-    bm_dtype: str = "float32"      # 'float32' | 'bfloat16' branch metrics
     renorm_every: int = 1          # path-metric renormalization period
     block_frames: int | str = 1    # intra-frame blocks per frame, or 'auto'
     overlap: int | None = None     # block training/truncation stages
@@ -102,12 +95,9 @@ class DecoderConfig:
             check_alignment(self.spec.f, self.spec.v1, self.spec.v2, self.rate)
         if self.radix not in (2, 4):
             raise ValueError(f"radix must be 2 or 4, got {self.radix}")
-        if self.layout not in ("lane", "sublane"):
-            raise ValueError(f"layout must be 'lane' or 'sublane', "
-                             f"got {self.layout!r}")
-        if self.bm_dtype not in ("float32", "bfloat16"):
-            raise ValueError(f"bm_dtype must be 'float32' or 'bfloat16', "
-                             f"got {self.bm_dtype!r}")
+        if self.backend not in ("reference", "kernel"):
+            raise ValueError(f"backend must be 'reference' or 'kernel', "
+                             f"got {self.backend!r}")
         if self.renorm_every < 0:
             raise ValueError(f"renorm_every must be >= 0, "
                              f"got {self.renorm_every}")
@@ -162,20 +152,15 @@ def _build_frame_decoder(cfg: DecoderConfig):
                 return jax.vmap(
                     lambda fr: decode_frame(fr, cfg.trellis, cfg.spec,
                                             cfg.renorm_every))(frames)
-    elif cfg.backend in ("kernel", "kernel_split"):
+    else:
         from ..kernels import ops as kops
-        unified = cfg.backend == "kernel"
 
         def decode_frames(frames):
             return kops.viterbi_decode_frames(
-                frames, cfg.trellis, cfg.spec, unified=unified,
+                frames, cfg.trellis, cfg.spec,
                 frames_per_tile=cfg.frames_per_tile,
                 pack_survivors=cfg.pack_survivors, radix=cfg.radix,
-                layout=cfg.layout, bm_dtype=cfg.bm_dtype,
-                block_frames=bf, overlap=ov,
-                interpret=cfg.interpret)
-    else:
-        raise ValueError(cfg.backend)
+                block_frames=bf, overlap=ov, interpret=cfg.interpret)
     return decode_frames
 
 

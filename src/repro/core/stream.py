@@ -600,10 +600,8 @@ def make_stream_decoder(cfg: DecoderConfig, *, chunk_frames: int | None = None,
     if chunk_frames is None:
         from ..kernels.autotune import plan_decode
         plan = plan_decode(
-            cfg.trellis, cfg.spec, unified=cfg.backend != "kernel_split",
-            pack_survivors=cfg.pack_survivors, radix=cfg.radix,
-            bm_dtype=cfg.bm_dtype, layout=cfg.layout,
-            num_devices=num_devices,
+            cfg.trellis, cfg.spec, pack_survivors=cfg.pack_survivors,
+            radix=cfg.radix, num_devices=num_devices,
             block_frames=cfg.block_frames, overlap=cfg.overlap)
         chunk_frames = plan.chunk_frames
     return StreamDecoder(cfg, chunk_frames, depth=depth, mesh=mesh,

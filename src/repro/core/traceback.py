@@ -14,9 +14,6 @@ Two tracebacks over one frame's survivor selectors ``sel`` (L, S):
 The parallel version is a *vectorized pointer chase*: all ``nsub`` cursors
 advance together, so the backward pass costs f0+v2s vector steps instead of
 f+v2 serial steps — the D/D' parallelism of Table I row (c).
-
-The ``*_frames`` variants trace back a whole batch of frames from the
-frame-major survivor stream the split kernel emits, vmapped over frames.
 """
 from __future__ import annotations
 
@@ -25,27 +22,18 @@ import jax.numpy as jnp
 
 from .trellis import Trellis
 
-__all__ = ["serial_traceback", "parallel_traceback",
-           "serial_traceback_frames", "parallel_traceback_frames"]
+__all__ = ["serial_traceback", "parallel_traceback"]
 
 
 def serial_traceback(sel: jax.Array, trellis: Trellis, start_state: jax.Array,
-                     v1: int, f: int, packed: bool = False) -> jax.Array:
-    """Chase from the last stage; return the f kept bits [v1, v1+f).
-
-    ``packed=True`` reads sel as (L, ceil(S/32)) int32 bit-packed selector
-    words (kernels/packing.py layout) instead of (L, S) one-per-cell.
-    """
+                     v1: int, f: int) -> jax.Array:
+    """Chase from the last stage; return the f kept bits [v1, v1+f)."""
     prev_state = jnp.asarray(trellis.prev_state)
     kshift = trellis.k - 2
 
     def step(j, sel_t):
         bit = j >> kshift
-        if packed:
-            p = (sel_t[j >> 5] >> (j & 31)) & 1
-        else:
-            p = sel_t[j]
-        i = prev_state[j, p]
+        i = prev_state[j, sel_t[j]]
         return i, bit
 
     _, bits = jax.lax.scan(step, start_state.astype(jnp.int32),
@@ -55,8 +43,7 @@ def serial_traceback(sel: jax.Array, trellis: Trellis, start_state: jax.Array,
 
 def parallel_traceback(sel: jax.Array, amax: jax.Array, trellis: Trellis,
                        v1: int, f: int, f0: int, v2s: int,
-                       start: str = "boundary",
-                       packed: bool = False) -> jax.Array:
+                       start: str = "boundary") -> jax.Array:
     """Parallel traceback over ``nsub = f // f0`` subframes.
 
     Args:
@@ -68,8 +55,6 @@ def parallel_traceback(sel: jax.Array, amax: jax.Array, trellis: Trellis,
             right overlap v2 must be >= v2s so the last subframe's chase
             start stays inside the frame.
       start: 'boundary' | 'fixed'.
-      packed: sel is (L, ceil(S/32)) int32 bit-packed words instead of
-        (L, S) one-selector-per-cell (kernels/packing.py layout).
 
     Returns: (f,) decoded bits.
     """
@@ -95,10 +80,7 @@ def parallel_traceback(sel: jax.Array, amax: jax.Array, trellis: Trellis,
     def step(states, r):
         t = e - r                                     # (nsub,) current stages
         bits = states >> kshift
-        if packed:
-            p = (sel32[t, states >> 5] >> (states & 31)) & 1
-        else:
-            p = sel32[t, states]                      # vectorized gather
+        p = sel32[t, states]                          # vectorized gather
         states = prev_state[states, p]
         return states, bits
 
@@ -111,28 +93,3 @@ def parallel_traceback(sel: jax.Array, amax: jax.Array, trellis: Trellis,
     kept = kept[::-1, :]                              # (f0, nsub) ascending
     return kept.T.reshape((f,))                       # subframes concatenated
 
-
-def serial_traceback_frames(sel: jax.Array, amax: jax.Array,
-                            trellis: Trellis, v1: int, f: int,
-                            packed: bool = False) -> jax.Array:
-    """Serial traceback of a frame batch -> (F, f) bits.
-
-    sel: (F, L, S|W); amax: (F, L) — each frame's chase starts from its
-    last-stage argmax.
-    """
-    tb = lambda s, a: serial_traceback(s, trellis, a[-1], v1, f,
-                                       packed=packed)
-    return jax.vmap(tb)(sel, amax)
-
-
-def parallel_traceback_frames(sel: jax.Array, amax: jax.Array,
-                              trellis: Trellis, v1: int, f: int, f0: int,
-                              v2s: int, start: str = "boundary",
-                              packed: bool = False) -> jax.Array:
-    """Parallel traceback of a frame batch -> (F, f) bits.
-
-    sel: (F, L, S|W); amax: (F, L).
-    """
-    tb = lambda s, a: parallel_traceback(
-        s, a, trellis, v1, f, f0, v2s, start, packed=packed)
-    return jax.vmap(tb)(sel, amax)

@@ -1,4 +1,1 @@
-from .sharding import (param_specs, param_shardings, batch_specs,
-                       cache_specs, moment_specs)  # noqa: F401
-from . import compress                             # noqa: F401
 from .stream import frame_mesh, make_sharded_frame_decoder  # noqa: F401

@@ -45,8 +45,8 @@ def make_sharded_frame_decoder(cfg: DecoderConfig, mesh: Mesh | None = None):
     F is padded up to a multiple of the mesh size (padding frames decode
     garbage from zero LLRs and are dropped before returning). Each shard
     runs the ordinary per-device frame decoder (the cache-shared closure
-    from make_frame_decoder), so every cfg backend — reference, unified
-    kernel, split kernel — shards identically.
+    from make_frame_decoder), so both cfg backends — reference and
+    kernel — shard identically.
     """
     mesh = mesh if mesh is not None else frame_mesh()
     local = make_frame_decoder(cfg)

@@ -1,20 +1,18 @@
-"""Pallas TPU kernels for the paper's compute hot-spot: compiled by Mosaic
-on a TPU (sublane layout), interpreted elsewhere, and checked bit-exact
-against ``ref`` in interpret mode; ``packing.Layout`` describes the two
-layouts.
+"""The unified Pallas TPU kernel for the paper's compute hot-spot:
+compiled by Mosaic on a TPU, interpreted elsewhere, and checked bit-exact
+against ``ref`` in interpret mode.
 
 Submodules (``ops``, ``ref``, ``autotune``, ``packing``, ...) are imported
-on first use rather than eagerly: ``core.traceback`` consumes the layout
-vocabulary of ``kernels.packing``, and an eager ``from . import ops`` here
-would re-enter ``repro.core`` mid-import — kernels.packing depends on
-nothing, everything above it may depend on it. Attribute access
-(``repro.kernels.ops``) and ``from repro.kernels import ops`` both work;
-the module __getattr__ below resolves them on demand.
+on first use rather than eagerly: ``core.pipeline`` imports ``kernels``
+lazily, and an eager ``from . import ops`` here would re-enter
+``repro.core`` mid-import. Attribute access (``repro.kernels.ops``) and
+``from repro.kernels import ops`` both work; the module __getattr__ below
+resolves them on demand.
 """
 import importlib
 
 _SUBMODULES = ("acs", "autotune", "block", "ops", "packing", "ref", "tables",
-               "tunedb", "viterbi_fwd", "viterbi_unified")
+               "tunedb", "viterbi_unified")
 
 
 def __getattr__(name):

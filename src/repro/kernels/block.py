@@ -10,10 +10,10 @@ stage *training* region on the left (ACS warm-up from a uniform metric,
 exactly like the frame's own v1) and *truncation* region on the right
 (traceback convergence, like v2), decode the blocks in parallel, and drop
 the overlap regions at merge. Blocks are just shorter frames laid out on
-the existing frame axis, so the unchanged unified/split kernels decode
-them — one long frame fills a tile the way many short frames do today,
-the per-tile scan shrinks from ``v1+f+v2`` to ``f/B + 2*overlap`` stages,
-and the bit-packed survivor machinery works as-is in both layouts.
+the existing frame axis, so the unchanged unified kernel decodes them —
+one long frame fills a tile the way many short frames do today, the
+per-tile scan shrinks from ``v1+f+v2`` to ``f/B + 2*overlap`` stages,
+and the bit-packed survivor machinery works as-is.
 
 Accuracy is the standard truncated-traceback trade-off: with ``overlap``
 at least ~5 constraint lengths the survivor paths have converged and the

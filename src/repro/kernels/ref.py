@@ -1,4 +1,4 @@
-"""Pure-jnp oracles for the Pallas kernels.
+"""Pure-jnp oracle for the Pallas kernel.
 
 Deliberately written on top of the already-unit-tested ``repro.core``
 reference algorithms (which are themselves validated against the encoder
@@ -9,13 +9,11 @@ from __future__ import annotations
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 
-from ..core.decoder import viterbi_forward
 from ..core.framed import FrameSpec, decode_frame
 from ..core.trellis import Trellis
 
-__all__ = ["unified_decode_frames_ref", "forward_frames_ref"]
+__all__ = ["unified_decode_frames_ref"]
 
 
 @partial(jax.jit, static_argnums=(1, 2))
@@ -24,11 +22,3 @@ def unified_decode_frames_ref(frames: jax.Array, trellis: Trellis,
     """(F, L, beta) -> (F, f) bits; oracle for viterbi_unified."""
     return jax.vmap(lambda fr: decode_frame(fr, trellis, spec))(frames)
 
-
-@partial(jax.jit, static_argnums=(1,))
-def forward_frames_ref(frames: jax.Array, trellis: Trellis):
-    """(F, L, beta) -> (sel (F,L,S) int8, amax (F,L)); oracle for viterbi_fwd."""
-    def one(fr):
-        sel, _, amax = viterbi_forward(fr, trellis)
-        return sel.astype(jnp.int8), amax
-    return jax.vmap(one)(frames)

@@ -3,10 +3,7 @@
 ``DecodeServer`` aggregates many independent, heterogeneous LLR streams
 into the large frame batches where the Pallas kernels' throughput lives;
 ``plan_cache.PLAN_CACHE`` is the process-global compiled-plan cache shared
-with the stream and pipeline layers. (The LM-serving demo in
-``repro.launch.serve`` / ``examples/serve_lm.py`` is unrelated scaffolding
-for the transformer side of this repo — THIS package is the Viterbi
-service.)
+with the stream and pipeline layers.
 """
 from .plan_cache import PLAN_CACHE, PlanCache          # noqa: F401
 from .metrics import BucketMetrics, ServeMetrics, FAULT_COUNTERS  # noqa: F401

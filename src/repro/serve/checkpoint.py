@@ -73,8 +73,7 @@ class CheckpointError(ServeError):
 #: DecoderConfig's plain (JSON-native) fields; trellis and spec are
 #: handled structurally.
 _CFG_FIELDS = ("rate", "backend", "interpret", "pack_survivors", "radix",
-               "frames_per_tile", "layout", "bm_dtype", "renorm_every",
-               "block_frames", "overlap")
+               "frames_per_tile", "renorm_every", "block_frames", "overlap")
 
 
 def encode_cfg(cfg: DecoderConfig) -> dict:
@@ -88,6 +87,15 @@ def encode_cfg(cfg: DecoderConfig) -> dict:
 
 
 def decode_cfg(data: dict) -> DecoderConfig:
+    """The DecoderConfig of ``encode_cfg``'s form. Older files may carry
+    ``layout`` (either survivor layout decoded the same bits, so it is
+    dropped) and ``bm_dtype``: ``"float32"`` is dropped, ``"bfloat16"``
+    is refused, since its bits were not the exact decode."""
+    if data.get("bm_dtype", "float32") != "float32":
+        raise CheckpointError(
+            f"a session decodes with bm_dtype={data['bm_dtype']!r}, which "
+            f"this server no longer has: its bits were not the exact "
+            f"decode, so it cannot be resumed bit-identically")
     trellis = make_trellis(int(data["trellis"]["k"]),
                            tuple(int(p) for p in data["trellis"]["polys"]))
     spec = FrameSpec(**data["spec"])

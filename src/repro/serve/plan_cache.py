@@ -64,8 +64,7 @@ def plan_attrs(cfg: DecoderConfig, nframes: int | None = None,
     tile = launch_tile(
         tr, cfg.spec.blocked(bf, ov) if bf > 1 else cfg.spec,
         -(-int(nframes) // ndev) * bf, frames_per_tile=cfg.frames_per_tile,
-        unified=cfg.backend == "kernel", pack_survivors=cfg.pack_survivors,
-        radix=cfg.radix, layout=cfg.layout, bm_dtype=cfg.bm_dtype)
+        pack_survivors=cfg.pack_survivors)
     attrs.update(frames_per_tile=tile.frames_per_tile,
                  vmem_bytes=tile.vmem_bytes)
     return attrs

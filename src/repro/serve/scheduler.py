@@ -123,9 +123,8 @@ def bucket_plan(cfg: DecoderConfig, num_devices: int = 1,
     pinned = (cfg.frames_per_tile
               if isinstance(cfg.frames_per_tile, int) else None)
     return plan_decode(
-        cfg.trellis, cfg.spec, unified=cfg.backend != "kernel_split",
-        pack_survivors=cfg.pack_survivors, radix=cfg.radix,
-        bm_dtype=cfg.bm_dtype, layout=cfg.layout, num_devices=num_devices,
+        cfg.trellis, cfg.spec, pack_survivors=cfg.pack_survivors,
+        radix=cfg.radix, num_devices=num_devices,
         chunk_frames=chunk_frames, frames_per_tile=pinned,
         block_frames=cfg.block_frames, overlap=cfg.overlap)
 

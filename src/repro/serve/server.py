@@ -523,10 +523,9 @@ class DecodeServer:
 
     def _ref_fallback(self, bucket: Bucket, nframes: int):
         """The degraded-mode decoder: same trellis/spec, reference
-        backend (bit-identical to the kernels at fp32 bm_dtype; bf16
-        buckets degrade to the fp32 reference, which is the BER-gated
-        direction). Never consults the fault injector — the fallback is
-        the path that must work when the fast path doesn't."""
+        backend (bit-identical to the kernel). Never consults the fault
+        injector — the fallback is the path that must work when the fast
+        path doesn't."""
         ref_cfg = dataclasses.replace(bucket.decode_cfg,
                                       backend="reference", renorm_every=1)
         return self.cache.batch_decoder(ref_cfg, nframes, mesh=bucket.mesh)

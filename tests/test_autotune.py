@@ -4,50 +4,61 @@ import pytest
 from repro.core import FrameSpec, STD_K7
 from repro.core.trellis import make_trellis
 from repro.kernels.autotune import (CANDIDATE_TILES, DEFAULT_VMEM_BUDGET,
+                                    LANES, kernel_shapes,
                                     mosaic_padded_bytes, plan_decode,
-                                    plan_tiles, split_vmem_bytes,
-                                    unified_vmem_bytes)
-from repro.kernels.packing import Layout
+                                    plan_tiles, unified_vmem_bytes,
+                                    vmem_limit)
 
 SPEC = FrameSpec(f=256, v1=20, v2=45, f0=32, v2s=45)
+MIB16 = 16 * 1024 * 1024
 
 
 def test_footprint_matches_kernel_scratch():
-    """The model's sel term is the kernel's (L, FT, S) int32 scratch, and
-    packing shrinks exactly that term 32x for S=64 (the acceptance spec:
-    (L, FT, S) -> (L, FT, S // 32))."""
-    L, FT, S = SPEC.frame_len, 8, STD_K7.num_states
+    """The model's sel term is the kernel's (L*S, lanes) int32 scratch of
+    a 128-frame chunk under Mosaic padding, and packing shrinks exactly
+    that term to (L*S/32, lanes) rows for S=64."""
+    L, FT, S = SPEC.frame_len, 128, STD_K7.num_states
     _, plain = unified_vmem_bytes(STD_K7, SPEC, FT)
     _, packed = unified_vmem_bytes(STD_K7, SPEC, FT, pack_survivors=True)
     d_plain, d_packed = dict(plain), dict(packed)
-    assert d_plain["sel_survivors"] == L * FT * S * 4
-    assert d_packed["sel_survivors"] == L * FT * (S // 32) * 4
-    assert d_plain["sel_survivors"] == 32 * d_packed["sel_survivors"]
+    assert d_plain["sel_survivors"] == mosaic_padded_bytes((L * S, 128), 4)
+    assert d_packed["sel_survivors"] == \
+        mosaic_padded_bytes((L * (S // 32), 128), 4)
+    assert d_plain["sel_survivors"] == L * S * 128 * 4
     # everything else is knob-independent
     for k in d_plain:
         if k != "sel_survivors":
             assert d_plain[k] == d_packed[k]
+    # the model prices exactly the arrays the kernel allocates
+    shapes = kernel_shapes(STD_K7, L, SPEC.f, FT, True)
+    assert [n for n, _, _ in shapes] == [n for n, _ in packed]
+    assert vmem_limit(shapes) == 32 << 20        # the limit's floor
 
 
 def test_footprint_scales_linearly_in_ft():
-    t8, _ = unified_vmem_bytes(STD_K7, SPEC, 8)
-    t32, _ = unified_vmem_bytes(STD_K7, SPEC, 32)
-    assert t32 == 4 * t8
+    t128, _ = unified_vmem_bytes(STD_K7, SPEC, 128)
+    t512, _ = unified_vmem_bytes(STD_K7, SPEC, 512)
+    assert t512 == 4 * t128
 
 
 def test_packed_plan_is_deeper():
-    plain = plan_tiles(STD_K7, SPEC)
-    packed = plan_tiles(STD_K7, SPEC, pack_survivors=True)
-    assert plain.frames_per_tile >= 8
-    assert packed.frames_per_tile >= 32          # the acceptance target
+    plain = plan_tiles(STD_K7, SPEC, vmem_budget=MIB16)
+    packed = plan_tiles(STD_K7, SPEC, pack_survivors=True,
+                        vmem_budget=MIB16)
+    assert plain.frames_per_tile >= LANES
+    assert packed.frames_per_tile >= 8 * LANES
     assert packed.frames_per_tile > plain.frames_per_tile
-    assert packed.vmem_bytes <= packed.budget == DEFAULT_VMEM_BUDGET
+    assert packed.vmem_bytes <= packed.budget == MIB16
+    # at the default budget the packed plan fills one 128-lane chunk
+    p = plan_tiles(STD_K7, SPEC, pack_survivors=True)
+    assert p.frames_per_tile == LANES
+    assert p.vmem_bytes <= p.budget == DEFAULT_VMEM_BUDGET
 
 
 def test_plan_respects_budget_and_floor():
-    # a tiny budget still yields the smallest candidate (kernel must run)
+    # a tiny budget still yields the smallest tile Mosaic can tile
     p = plan_tiles(STD_K7, SPEC, vmem_budget=1)
-    assert p.frames_per_tile == CANDIDATE_TILES[0]
+    assert p.frames_per_tile == LANES
     # a huge budget tops out at the largest candidate
     p = plan_tiles(STD_K7, SPEC, pack_survivors=True, vmem_budget=1 << 30)
     assert p.frames_per_tile == CANDIDATE_TILES[-1]
@@ -62,8 +73,8 @@ def test_plan_caps_at_stream_length():
 def test_plan_scales_with_state_count():
     """K=9 (S=256) frames are 4x heavier: the plan must shrink, not OOM."""
     k9 = make_trellis(9, (0o753, 0o561))
-    p7 = plan_tiles(STD_K7, SPEC, pack_survivors=True)
-    p9 = plan_tiles(k9, SPEC, pack_survivors=True)
+    p7 = plan_tiles(STD_K7, SPEC, pack_survivors=True, vmem_budget=MIB16)
+    p9 = plan_tiles(k9, SPEC, pack_survivors=True, vmem_budget=MIB16)
     assert p9.frames_per_tile < p7.frames_per_tile
     assert p9.vmem_bytes <= p9.budget
 
@@ -79,81 +90,17 @@ def test_mosaic_padding_model():
     assert mosaic_padded_bytes((64,), 4) == 8 * 128 * 4   # 1D: one full tile
 
 
-def test_lane_packing_evaporates_under_mosaic():
-    """The ROADMAP open item, as arithmetic: under padded accounting the
-    lane layout's packed sel term is as large as the unpacked one (both
-    lane-pad to 128), while the sublane layout's flat scratch keeps the
-    full 32x."""
-    _, lane_p = unified_vmem_bytes(STD_K7, SPEC, 32, pack_survivors=True,
-                                   mosaic=True)
-    _, lane_u = unified_vmem_bytes(STD_K7, SPEC, 32, mosaic=True)
-    assert dict(lane_p)["sel_survivors"] == dict(lane_u)["sel_survivors"]
-    _, sub_p = unified_vmem_bytes(STD_K7, SPEC, 128, pack_survivors=True,
-                                  layout=Layout.SUBLANE)
-    L, S = SPEC.frame_len, STD_K7.num_states
-    assert dict(sub_p)["sel_survivors"] == \
-        mosaic_padded_bytes((L * (S // 32), 128), 4)
-    # per frame, the flat sublane scratch is >32x below the lane layout's
-    # padded term (63x here: 128-lane padding of W=2 words)
-    assert 32 * dict(sub_p)["sel_survivors"] / 128 \
-        < dict(lane_p)["sel_survivors"] / 32
-
-
-def test_sublane_plan_doubles_frames_at_equal_budget():
-    """Acceptance criterion: under hardware-honest (mosaic) accounting at
-    the SAME 2 MiB budget, the sublane-major packed plan fits >= 2x the
-    frames per tile of the lane layout (and >= 2x PR 1's best recorded
-    auto plan, ft=32)."""
-    lane = plan_tiles(STD_K7, SPEC, pack_survivors=True, radix=4,
-                      mosaic=True)
-    sub = plan_tiles(STD_K7, SPEC, pack_survivors=True, radix=4,
-                     layout=Layout.SUBLANE)
-    assert sub.mosaic and sub.vmem_bytes <= sub.budget
-    assert sub.frames_per_tile >= 2 * lane.frames_per_tile
-    assert sub.frames_per_tile >= 2 * 32            # PR-1's BENCH best plan
-
-
-def test_split_model_is_smaller_and_plans_deeper():
-    """plan_tiles(unified=False) budgets the forward kernel's footprint
-    (no survivor scratch / traceback arrays), so at a pinched budget the
-    split plan fits at least as many frames per tile."""
-    for ft in (8, 32):
-        u, _ = unified_vmem_bytes(STD_K7, SPEC, ft, pack_survivors=True)
-        s, bd = split_vmem_bytes(STD_K7, SPEC, ft, pack_survivors=True)
-        assert s < u
-        assert {n for n, _ in bd} == {"llr_block", "bm_compressed",
-                                      "sel_stream", "amax_stream"}
-    budget = 300 * 1024     # fits split ft=32 (281 KiB), unified only ft=16
-    pu = plan_tiles(STD_K7, SPEC, pack_survivors=True, vmem_budget=budget)
-    ps = plan_tiles(STD_K7, SPEC, pack_survivors=True, vmem_budget=budget,
-                    unified=False)
-    assert ps.kernel == "split" and pu.kernel == "unified"
-    assert ps.frames_per_tile > pu.frames_per_tile
-
-
-def test_bf16_halves_bm_term():
-    _, f32 = unified_vmem_bytes(STD_K7, SPEC, 32, pack_survivors=True)
-    _, bf16 = unified_vmem_bytes(STD_K7, SPEC, 32, pack_survivors=True,
-                                 bm_dtype="bfloat16")
-    assert dict(bf16)["bm_compressed"] == dict(f32)["bm_compressed"] // 2
-    with pytest.raises(ValueError, match="bm_dtype"):
-        unified_vmem_bytes(STD_K7, SPEC, 32, bm_dtype="float16")
-
-
 def test_plan_decode_full_plan():
-    """plan_decode returns everything the front-end executes: auto layout
-    resolves to sublane for this geometry, kernel kwargs splat into ops,
-    and the chunk is a multiple of tiles x devices."""
+    """plan_decode returns everything the front-end executes: kernel
+    kwargs splat into ops, and the chunk is a multiple of tiles x
+    devices."""
     p = plan_decode(STD_K7, SPEC, num_devices=4)
-    assert p.tile.layout is Layout.SUBLANE
-    assert p.unified and p.pack_survivors and p.radix == 4
+    assert p.pack_survivors and p.radix == 4
+    assert p.frames_per_tile == LANES
     assert p.chunk_frames == 2 * p.frames_per_tile * 4
-    kw = p.kernel_kwargs()
-    assert kw["layout"] == "sublane" and kw["unified"] is True
-    assert kw["frames_per_tile"] == p.frames_per_tile
-    # split planning flows through too
-    ps = plan_decode(STD_K7, SPEC, unified=False)
-    assert not ps.unified and ps.tile.kernel == "split"
+    assert p.kernel_kwargs() == dict(
+        frames_per_tile=p.frames_per_tile, pack_survivors=True, radix=4,
+        block_frames=1, overlap=0)
 
 
 def test_candidates_lift_the_256_cap():
@@ -163,18 +110,17 @@ def test_candidates_lift_the_256_cap():
     MAX_FRAMES_PER_TILE backstop bounds an unlimited budget."""
     from repro.kernels.autotune import MAX_FRAMES_PER_TILE
     assert CANDIDATE_TILES[-1] == MAX_FRAMES_PER_TILE > 256
-    p = plan_tiles(STD_K7, SPEC, pack_survivors=True, radix=4,
-                   layout=Layout.SUBLANE, vmem_budget=8 * 1024 * 1024)
+    p = plan_tiles(STD_K7, SPEC, pack_survivors=True,
+                   vmem_budget=8 * 1024 * 1024)
     assert p.frames_per_tile == 512 > 256
     assert p.vmem_bytes <= p.budget
-    p2 = plan_tiles(STD_K7, SPEC, pack_survivors=True, radix=4,
-                    layout=Layout.SUBLANE, vmem_budget=1 << 30,
+    p2 = plan_tiles(STD_K7, SPEC, pack_survivors=True, vmem_budget=1 << 30,
                     max_frames=300)
     assert p2.frames_per_tile == 512          # smallest candidate >= 300
 
 
 def test_kernel_runs_beyond_256_frames_per_tile():
-    """A >256 sublane tile actually decodes, bit-exact vs the reference
+    """A >256 frame tile actually decodes, bit-exact vs the reference
     (the plan space beyond the old cap is real, not just arithmetic)."""
     import numpy as np
     import jax.numpy as jnp
@@ -187,7 +133,7 @@ def test_kernel_runs_beyond_256_frames_per_tile():
     want = np.asarray(ref.unified_decode_frames_ref(frames, STD_K7, spec))
     got = np.asarray(ops.viterbi_decode_frames(
         frames, STD_K7, spec, frames_per_tile=512, pack_survivors=True,
-        radix=4, layout="sublane", interpret=True))
+        radix=4, interpret=True))
     assert np.array_equal(got, want)
 
 
@@ -204,8 +150,8 @@ def test_plan_cache_key_and_pinned_tile():
     assert a.cache_key() != c.cache_key()
     d = plan_decode(STD_K7, SPEC, chunk_frames=7)
     assert a.cache_key() != d.cache_key()
-    p = plan_decode(STD_K7, SPEC, layout="lane", frames_per_tile=8)
-    assert p.frames_per_tile == 8 and p.tile.layout is Layout.LANE
+    p = plan_decode(STD_K7, SPEC, frames_per_tile=8)
+    assert p.frames_per_tile == 8
     assert p.chunk_frames == 2 * 8            # chunk follows the pinned tile
 
 
@@ -225,17 +171,11 @@ def test_plan_identity_differs_for_every_knob():
     distinct identity, so the plan cache and serve buckets can never
     alias two plans that compile or decode differently."""
     import dataclasses
-    base = plan_decode(STD_K7, SPEC, layout="sublane")
+    base = plan_decode(STD_K7, SPEC)
     variants = [
         ("frames_per_tile",
          dataclasses.replace(base, tile=dataclasses.replace(
              base.tile, frames_per_tile=base.tile.frames_per_tile * 2))),
-        ("kernel", dataclasses.replace(base, tile=dataclasses.replace(
-            base.tile, kernel="split"))),
-        ("layout", dataclasses.replace(base, tile=dataclasses.replace(
-            base.tile, layout=Layout.LANE))),
-        ("bm_dtype", dataclasses.replace(base, tile=dataclasses.replace(
-            base.tile, bm_dtype="bfloat16"))),
         ("pack_survivors", dataclasses.replace(base, pack_survivors=False)),
         ("radix", dataclasses.replace(base, radix=2)),
         ("chunk_frames",
@@ -273,11 +213,9 @@ def test_fingerprint_stable_across_processes():
         "from repro.core import FrameSpec, STD_K7\n"
         "from repro.kernels.autotune import plan_decode\n"
         "spec = FrameSpec(f=256, v1=20, v2=45, f0=32, v2s=45)\n"
-        "p = plan_decode(STD_K7, spec, layout='sublane',\n"
-        "                block_frames=4, overlap=45)\n"
+        "p = plan_decode(STD_K7, spec, block_frames=4, overlap=45)\n"
         "print(p.fingerprint())\n")
-    here = plan_decode(STD_K7, SPEC, layout="sublane",
-                       block_frames=4, overlap=45)
+    here = plan_decode(STD_K7, SPEC, block_frames=4, overlap=45)
     assert here.block_frames == 4 and here.overlap == 45
     out = subprocess.run([sys.executable, "-c", prog], check=True,
                          capture_output=True, text=True, env=None)

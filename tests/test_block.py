@@ -11,8 +11,8 @@ The two exactness regimes anchor everything (see kernels/block.py):
   bit-identical to the unblocked one.
 
 Between the regimes, blocking is the truncated-traceback approximation:
-gated here against the exact decode at 1e-3 BER (the bf16 gating pattern
-of tests/test_ber.py), with the default overlap ~5 constraint lengths.
+gated here against the exact decode at 1e-3 BER, with the default overlap
+~5 constraint lengths.
 """
 import numpy as np
 import pytest
@@ -21,7 +21,8 @@ import jax
 import jax.numpy as jnp
 
 from conftest import noisy_llr
-from repro.core import DecoderConfig, FrameSpec, STD_K7, make_decoder
+from repro.core import (DecoderConfig, FrameSpec, STD_K7, make_decoder,
+                        make_trellis)
 from repro.core.decoder import viterbi_decode
 from repro.core.framed import frame_llr, merge_blocks, reframe_blocks
 from repro.core.stream import stream_decode
@@ -37,8 +38,8 @@ SERIAL = FrameSpec(f=256, v1=20, v2=20)
 PARALLEL = FrameSpec(f=64, v1=16, v2=20, f0=16, v2s=12)
 
 
-def _frames(spec, n, rng):
-    llr = rng.standard_normal((n, 2)).astype(np.float32)
+def _frames(spec, n, rng, beta=2):
+    llr = rng.standard_normal((n, beta)).astype(np.float32)
     return jnp.asarray(llr), frame_llr(jnp.asarray(llr), spec)
 
 
@@ -113,19 +114,22 @@ def test_full_overlap_value():
 
 
 # -- kernel-path exactness ------------------------------------------------
-@pytest.mark.parametrize("layout", ["lane", "sublane"])
+@pytest.mark.parametrize("code", [(5, (0o23, 0o33)), (7, (0o133, 0o171)),
+                                  (9, (0o557, 0o663, 0o711))],
+                         ids=["K5", "K7", "K9"])
 @pytest.mark.parametrize("pack", [False, True])
-def test_kernel_fine_framing_equivalence(layout, pack, rng):
+def test_kernel_fine_framing_equivalence(code, pack, rng):
     """Blocked kernel decode == the same kernel decoding the fine framing
-    directly, per layout and packing (the survivor machinery is reused
-    unchanged by blocks)."""
-    llr, frames = _frames(SERIAL, 8 * SERIAL.f, rng)
+    directly, per trellis of the benchmark and packing (the survivor
+    machinery is reused unchanged by blocks)."""
+    tr = make_trellis(*code)
+    llr, frames = _frames(SERIAL, 8 * SERIAL.f, rng, tr.beta)
     blocked = ops.viterbi_decode_frames(
-        frames, STD_K7, SERIAL, block_frames=4, overlap=16,
-        pack_survivors=pack, layout=layout)
+        frames, tr, SERIAL, block_frames=4, overlap=16,
+        pack_survivors=pack)
     fine = ops.viterbi_decode_frames(
-        frame_llr(llr, SERIAL.blocked(4, 16)), STD_K7, SERIAL.blocked(4, 16),
-        pack_survivors=pack, layout=layout)
+        frame_llr(llr, SERIAL.blocked(4, 16)), tr, SERIAL.blocked(4, 16),
+        pack_survivors=pack)
     assert blocked.shape == (8, SERIAL.f)
     assert np.array_equal(np.asarray(blocked).reshape(-1),
                           np.asarray(fine).reshape(-1))
@@ -146,10 +150,10 @@ def test_kernel_degenerate_full_overlap_bit_identity(spec, B, rng):
     assert np.array_equal(np.asarray(plain), np.asarray(blocked))
 
 
-@pytest.mark.parametrize("backend", ["kernel", "kernel_split"])
+@pytest.mark.parametrize("backend", ["kernel"])
 def test_blocked_backends_match_blocked_reference(backend, rng):
-    """All three backends apply the SAME decomposition — bit-identical
-    under blocking, so serve degrade/failover to reference is safe."""
+    """Both backends apply the SAME decomposition — bit-identical under
+    blocking, so serve degrade/failover to reference is safe."""
     spec = FrameSpec(f=128, v1=16, v2=20)
     n = 4 * spec.f
     llr = jnp.asarray(rng.standard_normal((n, 2)).astype(np.float32))
@@ -194,8 +198,8 @@ def test_plan_decode_block_roundtrip(rng):
     derived block spec (frames_per_tile counts blocks), keeps chunk_frames
     in outer frames, and kernel_kwargs() drives the kernel directly."""
     spec = FrameSpec(f=4096, v1=32, v2=32, f0=32, v2s=32)
-    seq = plan_decode(STD_K7, spec, layout="sublane")
-    blk = plan_decode(STD_K7, spec, layout="sublane", block_frames="auto")
+    seq = plan_decode(STD_K7, spec)
+    blk = plan_decode(STD_K7, spec, block_frames="auto")
     assert blk.block_frames > 1 and blk.overlap == 35
     assert blk.frames_per_tile > seq.frames_per_tile
     assert blk.cache_key() != seq.cache_key()

@@ -294,6 +294,65 @@ def test_checkpoint_corrupt_fault_is_caught_at_restore():
     assert DecodeServer.restore(good).num_sessions == 1
 
 
+#: a checkpoint written before the kernel lost its interpret-only
+#: survivor layout and bfloat16 branch metrics: its session configs still
+#: carry ``layout`` and ``bm_dtype``
+OLD_CKPT = os.path.join(os.path.dirname(__file__), "data",
+                        "checkpoint_rate12_k7.json")
+
+
+def _old_copy(tmp_path, **cfg_keys):
+    """OLD_CKPT with ``cfg_keys`` set in every session's config and its
+    CRC made good again."""
+    import zlib
+    from repro.serve.checkpoint import _canonical
+    doc = json.load(open(OLD_CKPT))
+    for row in doc["payload"]["sessions"]:
+        row["cfg"].update(cfg_keys)
+    doc["crc"] = zlib.crc32(_canonical(doc["payload"]))
+    path = str(tmp_path / "old_copy.json")
+    open(path, "w").write(json.dumps(doc))
+    return path
+
+
+def test_old_checkpoint_restores_bit_for_bit(tmp_path):
+    """The file restores unedited: every session's stream state and
+    undelivered bits come back as saved, and its config as saved less
+    the two retired keys."""
+    old = json.load(open(OLD_CKPT))["payload"]
+    srv = DecodeServer.restore(OLD_CKPT, cache=PlanCache())
+    new = json.load(open(srv.checkpoint(str(tmp_path / "again.json"))))
+    for was, now in zip(old["sessions"], new["payload"]["sessions"]):
+        assert now["ctx"] == was["ctx"] and now["ready"] == was["ready"]
+        assert now["cfg"] == {k: v for k, v in was["cfg"].items()
+                              if k not in ("layout", "bm_dtype")}
+
+
+@pytest.mark.parametrize("layout", ["lane", "sublane"])
+def test_old_checkpoint_restores_either_layout(tmp_path, layout):
+    """Both survivor layouts decoded the same bits: either restores."""
+    srv = DecodeServer.restore(_old_copy(tmp_path, layout=layout),
+                               cache=PlanCache())
+    assert srv.num_sessions == 2
+
+
+def test_old_checkpoint_with_bfloat16_metrics_is_refused(tmp_path):
+    """bfloat16 branch metrics were not the exact decode: such a session
+    cannot be resumed bit-identically, so the restore refuses it."""
+    with pytest.raises(CheckpointError, match="bfloat16"):
+        DecodeServer.restore(_old_copy(tmp_path, bm_dtype="bfloat16"),
+                             cache=PlanCache())
+
+
+def test_new_checkpoint_has_no_retired_keys(tmp_path):
+    srv = DecodeServer(cache=PlanCache())
+    srv.open_session(DecoderConfig(spec=SPEC), chunk_frames=2)
+    doc = json.load(open(srv.checkpoint(str(tmp_path / "new.json"))))
+    (row,) = doc["payload"]["sessions"]
+    assert not {"layout", "bm_dtype"} & set(row["cfg"])
+    assert doc["schema"] == "repro.serve.checkpoint/v1"
+
+
 def test_drain_refuses_admission_and_pushes_then_snapshots():
     cfg = DecoderConfig(spec=SPEC)
     srv = DecodeServer(slots=2, cache=PlanCache())

@@ -14,7 +14,7 @@ from repro.compile_cache import CHECKOUT_CACHE, use_compile_cache  # noqa: E402
 
 #: the chip's kernel path, interpreted: what DecoderConfig resolves to on
 #: a TPU, with interpret mode on
-OVER = dict(backend="kernel", layout="sublane", interpret=True)
+OVER = dict(backend="kernel", interpret=True)
 
 
 def _key(i):
@@ -60,10 +60,10 @@ def test_rehearse_phase_d_four_devices(four_cpu_devices):
 
 
 def test_smoke_checks_fail_loudly():
-    """A phase whose config does not select the sublane kernel, a kernel
-    traced in interpret mode, or a phase in which no kernel compiled is a
-    failure, not a pass."""
-    with pytest.raises(chip_smoke.SmokeFailure, match="sublane kernel"):
+    """A phase whose config does not select the kernel, a kernel traced in
+    interpret mode, or a phase in which no kernel compiled is a failure,
+    not a pass."""
+    with pytest.raises(chip_smoke.SmokeFailure, match="select the kernel"):
         chip_smoke.kernel_cfg({"backend": "reference"},
                               spec=chip_smoke.SPEC_12)
     with pytest.raises(chip_smoke.SmokeFailure, match="interpret"):

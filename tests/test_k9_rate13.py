@@ -33,7 +33,7 @@ BLOCK, TAIL = 268, 8
 SPEC = FrameSpec(f=268, v1=36, v2=54, f0=67, v2s=54)
 SPEC12 = FrameSpec(f=64, v1=16, v2=20)
 SPEC34 = FrameSpec(f=63, v1=21, v2=21)
-KERNEL = dict(backend="kernel", interpret=True, layout="sublane")
+KERNEL = dict(backend="kernel", interpret=True)
 CFG = DecoderConfig(trellis=K9, spec=SPEC, rate="1/3", **KERNEL)
 DATA = os.path.join(os.path.dirname(__file__), "data")
 

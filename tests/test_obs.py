@@ -491,10 +491,9 @@ def test_plan_decode_span_carries_chosen_plan_and_vmem():
     t = Tracer()
     set_tracer(t)
     spec = FrameSpec(f=256, v1=20, v2=45, f0=32, v2s=45)
-    plan = plan_decode(STD_K7, spec, layout="auto")
+    plan = plan_decode(STD_K7, spec)
     (rec,) = [r for r in t.spans() if r.name == "plan_decode"]
     a = rec.attrs
-    assert a["kernel"] == "unified"
     assert a["frames_per_tile"] == plan.frames_per_tile
     assert a["chunk_frames"] == plan.chunk_frames
     assert a["vmem_bytes"] > 0 and a["vmem_budget"] > 0
@@ -517,7 +516,7 @@ def test_kernel_trace_event_fires_once_per_compile():
                                   frames_per_tile=8).block_until_ready()
     evs = [r for r in t.spans() if r.name == "kernel_trace"]
     assert len(evs) == 1                     # one real compile
-    assert evs[0].attrs["kernel"] == "unified"
+    assert evs[0].attrs["frames_per_tile"] == 8
     assert evs[0].attrs["frames_per_tile"] == 8
     assert t.counters()["kernel_traces"] == 1
 
@@ -552,7 +551,7 @@ def _kernel_server_k5():
     from repro.serve import DecodeServer, PlanCache
     spec = FrameSpec(f=64, v1=16, v2=20, f0=16, v2s=20)
     cfg = DecoderConfig(trellis=make_trellis(5, (0o23, 0o35)), spec=spec,
-                        backend="kernel", interpret=True, layout="sublane")
+                        backend="kernel", interpret=True)
     srv = DecodeServer(slots=2, cache=PlanCache())
     sid = srv.open_session(cfg, chunk_frames=2)
     rx = np.random.default_rng(0).standard_normal((5 * 64, 2))

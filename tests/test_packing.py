@@ -1,37 +1,42 @@
-"""Unit tests for kernels/packing.py — survivor bit-pack round trips in
-both physical layouts (lane-packed and Mosaic-native sublane-packed)."""
+"""Unit tests for kernels/packing.py — the survivor bit-packing the kernel
+does each stage (``pack_rows``: states on sublanes, frames on lanes), and
+the word select its traceback reads it back with."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.packing import (BITS, Layout, extract_bit, pack_bits,
-                                   packed_width, unpack_bits)
+from repro.kernels.packing import BITS, pack_rows, packed_width
 
 
-@pytest.mark.parametrize("n", [1, 8, 16, 31, 32, 33, 64, 100, 256])
-def test_pack_unpack_roundtrip(rng, n):
-    sel = rng.integers(0, 2, size=(5, 7, n))
-    packed = pack_bits(jnp.asarray(sel))
-    assert packed.shape == (5, 7, packed_width(n))
-    assert packed.dtype == jnp.int32
-    back = np.asarray(unpack_bits(packed, n))
-    assert np.array_equal(back, sel)
+def _packed(sel) -> np.ndarray:
+    """(n, N) selectors -> (packed_width(n), N) int32 words."""
+    return np.concatenate([np.asarray(w) for w in pack_rows(jnp.asarray(sel))])
+
+
+def _unpack(words: np.ndarray, n: int) -> np.ndarray:
+    """Numpy inverse: bit s % 32 of word s // 32, for s < n."""
+    s = np.arange(n)
+    return (words[s // BITS] >> (s % BITS)[:, None]) & 1
+
+
+def _select(words: np.ndarray, states: np.ndarray) -> np.ndarray:
+    """The kernel traceback's read of state ``states[j]`` in lane j: the
+    word ``states >> 5`` picked by compares, then bit ``states & 31``."""
+    word = words[0]
+    for w in range(1, words.shape[0]):
+        word = np.where((states >> 5) == w, words[w], word)
+    return (word >> (states & 31)) & 1
 
 
 @pytest.mark.parametrize("n", [1, 8, 31, 32, 33, 64, 100])
 def test_pack_unpack_roundtrip_sublane(rng, n):
-    """SUBLANE packs axis -2 (states on sublanes) and leaves the trailing
-    payload (frames-on-lanes) axis alone — for any n, incl. n % 32 != 0."""
-    sel = rng.integers(0, 2, size=(3, n, 6))
-    packed = pack_bits(jnp.asarray(sel), Layout.SUBLANE)
-    assert packed.shape == (3, packed_width(n), 6)
-    assert packed.dtype == jnp.int32
-    back = np.asarray(unpack_bits(packed, n, Layout.SUBLANE))
-    assert np.array_equal(back, sel)
-    # the two layouts hold identical words, just transposed
-    lane = pack_bits(jnp.asarray(sel.swapaxes(-1, -2)))
-    assert np.array_equal(np.asarray(lane).swapaxes(-1, -2),
-                          np.asarray(packed))
+    """pack_rows packs the state axis (sublanes) and leaves the trailing
+    frames-on-lanes axis alone — for any n, incl. n % 32 != 0."""
+    sel = rng.integers(0, 2, size=(n, 6))
+    words = pack_rows(jnp.asarray(sel))
+    assert len(words) == packed_width(n)
+    assert all(w.shape == (1, 6) and w.dtype == jnp.int32 for w in words)
+    assert np.array_equal(_unpack(_packed(sel), n), sel)
 
 
 def test_packed_width():
@@ -41,51 +46,25 @@ def test_packed_width():
 
 def test_layout_matches_numpy_bitorder(rng):
     """State s lands at bit s%32 of word s//32 (contiguous little-endian)."""
-    sel = rng.integers(0, 2, size=(64,))
-    packed = np.asarray(pack_bits(jnp.asarray(sel)))
-    want = np.packbits(sel.astype(np.uint8), bitorder="little")
+    sel = rng.integers(0, 2, size=(64, 1))
+    packed = _packed(sel)[:, 0]
+    want = np.packbits(sel[:, 0].astype(np.uint8), bitorder="little")
     assert np.array_equal(packed.view(np.uint8), want)
 
 
 def test_sign_bit_roundtrip():
     """Bit 31 uses the int32 sign bit; wraparound must keep it exact."""
-    sel = np.zeros(32, np.int64)
+    sel = np.zeros((32, 1), np.int64)
     sel[31] = 1
-    packed = np.asarray(pack_bits(jnp.asarray(sel)))
-    assert packed[0] == np.int32(-2**31)
-    assert np.array_equal(np.asarray(unpack_bits(jnp.asarray(packed), 32)),
-                          sel)
-    # same word, sublane orientation
-    packed_s = np.asarray(pack_bits(jnp.asarray(sel)[:, None],
-                                    Layout.SUBLANE))
-    assert packed_s[0, 0] == np.int32(-2**31)
-
-
-@pytest.mark.parametrize("n", [8, 64, 100])
-def test_extract_bit_matches_indexing(rng, n):
-    sel = rng.integers(0, 2, size=(4, n))
-    packed = pack_bits(jnp.asarray(sel))
-    states = jnp.asarray(rng.integers(0, n, size=(4,)), jnp.int32)
-    got = np.asarray(extract_bit(packed, states))
-    want = sel[np.arange(4), np.asarray(states)]
-    assert np.array_equal(got, want)
+    packed = _packed(sel)
+    assert packed[0, 0] == np.int32(-2**31)
+    assert np.array_equal(_unpack(packed, 32), sel)
 
 
 @pytest.mark.parametrize("n", [8, 33, 64, 100])
 def test_extract_bit_matches_indexing_sublane(rng, n):
-    sel = rng.integers(0, 2, size=(4, n, 9))
-    packed = pack_bits(jnp.asarray(sel), Layout.SUBLANE)
-    states = jnp.asarray(rng.integers(0, n, size=(4, 9)), jnp.int32)
-    got = np.asarray(extract_bit(packed, states, Layout.SUBLANE))
-    i, j = np.mgrid[0:4, 0:9]
-    assert np.array_equal(got, sel[i, np.asarray(states), j])
-
-
-def test_extract_bit_broadcasts(rng):
-    sel = rng.integers(0, 2, size=(3, 5, 64))
-    packed = pack_bits(jnp.asarray(sel))
-    states = jnp.asarray(rng.integers(0, 64, size=(3, 5)), jnp.int32)
-    got = np.asarray(extract_bit(packed, states))
-    i, j = np.mgrid[0:3, 0:5]
-    assert np.array_equal(got, sel[i, j, np.asarray(states)])
-    assert BITS == 32
+    """The traceback's word select reads back each lane's state bit."""
+    sel = rng.integers(0, 2, size=(n, 9))
+    states = rng.integers(0, n, size=(9,)).astype(np.int32)
+    got = _select(_packed(sel), states)
+    assert np.array_equal(got, sel[states, np.arange(9)])

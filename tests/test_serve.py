@@ -296,8 +296,7 @@ def test_bucket_plan_matches_stream_default():
     cfg = DecoderConfig(spec=SPEC, backend="kernel")
     plan = bucket_plan(cfg)
     want = plan_decode(STD_K7, SPEC, pack_survivors=cfg.pack_survivors,
-                       radix=cfg.radix, bm_dtype=cfg.bm_dtype,
-                       layout=cfg.layout, num_devices=1)
+                       radix=cfg.radix, num_devices=1)
     assert plan.cache_key() == want.cache_key()
     assert plan.fingerprint() == want.fingerprint()
     assert len(plan.fingerprint()) == 10

@@ -54,13 +54,11 @@ def test_stream_decoder_is_reusable_after_flush(rng):
         assert np.array_equal(got, want), trial
 
 
-@pytest.mark.parametrize("backend", ["kernel", "kernel_split"])
+@pytest.mark.parametrize("backend", ["kernel"])
 def test_stream_kernel_backends(rng, backend):
     n = 2000
     llr, _ = _llr(n, rng)
-    # the split kernel has the lane layout only
-    layout = "lane" if backend == "kernel_split" else "sublane"
-    cfg = DecoderConfig(spec=SPEC, backend=backend, layout=layout)
+    cfg = DecoderConfig(spec=SPEC, backend=backend)
     want = np.asarray(make_decoder(cfg)(jnp.asarray(llr), n))
     got = stream_decode(cfg, llr, n, chunk_frames=8)
     assert np.array_equal(got, want)
@@ -84,8 +82,7 @@ def test_default_chunk_comes_from_plan():
     cfg = DecoderConfig(spec=SPEC, backend="kernel")
     dec = make_stream_decoder(cfg)
     plan = plan_decode(cfg.trellis, SPEC, pack_survivors=cfg.pack_survivors,
-                       radix=cfg.radix, bm_dtype=cfg.bm_dtype,
-                       layout=cfg.layout, num_devices=1)
+                       radix=cfg.radix, num_devices=1)
     assert dec.chunk_frames == plan.chunk_frames == 2 * plan.frames_per_tile
 
 

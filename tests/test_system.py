@@ -1,10 +1,6 @@
 """End-to-end system test: the paper's verification loop (Fig. 8) through
 the FULL production path — depuncture -> framing -> unified Pallas kernel
-(interpret) -> stitch — plus an elasticity integration test."""
-import os
-import subprocess
-import sys
-
+(interpret) -> stitch."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -29,67 +25,10 @@ def test_sdr_receiver_end_to_end_kernel_path(rng):
     b = float(ber(out, bits))
     assert b < 2e-3, b        # ~theory at 3 dB with parallel traceback
 
-    # the split (prior-work) backend decodes identically
+    # the pure-JAX reference backend decodes identically
     cfg2 = DecoderConfig(
         spec=FrameSpec(f=256, v1=20, v2=45, f0=32, v2s=45),
-        backend="kernel_split", interpret=True)
+        backend="reference")
     out2 = make_decoder(cfg2)(rx, n)
     assert np.array_equal(np.asarray(out), np.asarray(out2))
 
-
-ELASTIC = r"""
-import os
-os.environ["XLA_FLAGS"]="--xla_force_host_platform_device_count=8"
-import sys
-sys.path.insert(0, "src")
-import jax, jax.numpy as jnp, numpy as np, tempfile
-from jax.sharding import Mesh
-from repro.configs import get_config
-from repro.models import build_model
-from repro.optim import adamw, constant
-from repro.train import make_train_step
-from repro.train import checkpoint as ckpt
-from repro.distributed.sharding import param_shardings
-
-cfg = get_config("qwen3_32b", reduced=True)
-m = build_model(cfg)
-opt = adamw(constant(1e-3))
-step = make_train_step(m, opt)
-b = {"tokens": jnp.ones((4, 16), jnp.int32), "labels": jnp.ones((4, 16), jnp.int32)}
-
-devs = np.array(jax.devices())
-mesh8 = Mesh(devs.reshape(4, 2), ("data", "model"))
-params = m.init(jax.random.PRNGKey(0))
-psh = param_shardings(mesh8, params)
-params = jax.tree.map(jax.device_put, params, psh)
-opt_state = opt.init(params)
-with mesh8:
-    params, opt_state, met = jax.jit(step)(params, opt_state, b)
-with tempfile.TemporaryDirectory() as d:
-    ckpt.save(d, 0, {"params": params, "opt": opt_state})
-    # reference: one more step on the ORIGINAL mesh
-    with mesh8:
-        _, _, met_ref = jax.jit(step)(params, opt_state, b)
-    # ELASTIC RESCALE: restore onto a 2-device mesh (6 "failed" devices)
-    mesh2 = Mesh(devs[:2].reshape(2, 1), ("data", "model"))
-    psh2 = param_shardings(mesh2, params)
-    state2 = ckpt.restore(d, 0, {"params": params, "opt": opt_state},
-                          {"params": psh2, "opt": {"m": psh2, "v": psh2,
-                           "step": jax.NamedSharding(mesh2, jax.sharding.PartitionSpec())}})
-    with mesh2:
-        p3, o3, met3 = jax.jit(step)(state2["params"], state2["opt"], b)
-    assert np.isfinite(float(met3["loss"]))
-print("ELASTIC_OK", float(met_ref["loss"]), float(met3["loss"]))
-"""
-
-
-def test_elastic_rescale_across_meshes():
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    r = subprocess.run([sys.executable, "-c", ELASTIC], capture_output=True,
-                       text=True, timeout=600, env=env,
-                       cwd=os.path.join(os.path.dirname(__file__), ".."))
-    assert "ELASTIC_OK" in r.stdout, r.stdout[-2000:] + r.stderr[-2000:]
-    # losses from the 8-dev and 2-dev meshes agree (same math, resharded)
-    _, l8, l2 = r.stdout.split()[:3]
-    assert abs(float(l8) - float(l2)) < 5e-2

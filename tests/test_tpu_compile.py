@@ -81,7 +81,7 @@ def _compile(fn, shape, sharding):
 ])
 def test_unified_kernel_phase_a(one_chip, frames, knobs):
     fn = lambda fr: ops.viterbi_decode_frames(      # noqa: E731
-        fr, STD_K7, SPEC_A, layout="sublane", interpret=False, **knobs)
+        fr, STD_K7, SPEC_A, interpret=False, **knobs)
     _compile(fn, (frames, SPEC_A.frame_len, 2), one_chip)
 
 
@@ -95,7 +95,7 @@ def test_serve_bucket_batch_decoder(one_chip, code, spec, frames):
     blocks of the K=9 rate-1/3 code."""
     trellis = make_trellis(*code)
     cfg = DecoderConfig(trellis=trellis, spec=spec, backend="kernel",
-                        interpret=False, layout="sublane")
+                        interpret=False)
     fn = PlanCache().batch_decoder(cfg, frames)
     _compile(fn, (frames, spec.frame_len, trellis.beta), one_chip)
 
@@ -106,7 +106,7 @@ def test_long_frame_phase_c(one_chip, blocked):
     bf, ov = resolve_block(STD_K7, SPEC_C, "auto" if blocked else 1, None)
     assert (bf > 1) is blocked
     fn = lambda fr: ops.viterbi_decode_frames(      # noqa: E731
-        fr, STD_K7, SPEC_C, layout="sublane", interpret=False,
+        fr, STD_K7, SPEC_C, interpret=False,
         block_frames=bf, overlap=ov)
     _compile(fn, (8, SPEC_C.frame_len, 2), one_chip)
 
@@ -121,8 +121,7 @@ def test_sharded_serve_batch_four_chips(topo, spec, frames):
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     mesh = Mesh(np.array(topo.devices), ("frames",))
-    cfg = DecoderConfig(spec=spec, backend="kernel", interpret=False,
-                        layout="sublane")
+    cfg = DecoderConfig(spec=spec, backend="kernel", interpret=False)
     fn = PlanCache().batch_decoder(cfg, frames, mesh=mesh)
     compiled = _compile(fn, (frames, spec.frame_len, 2),
                         NamedSharding(mesh, P("frames")))
