@@ -16,7 +16,9 @@ bounding the metric growth fp32/bf16 must absorb.
 serve push paths; ``make_decoder`` applies the same rule in-graph. Both
 are BIT-IDENTICAL on clean inputs: values that are finite and within
 ``±clip`` pass through untouched (the host path returns the input array
-itself when nothing needs fixing).
+itself when nothing needs fixing, and learns that from one max and one
+min, with no temporary; only a push that fails that check pays for the
+full scan).
 """
 from __future__ import annotations
 
@@ -49,6 +51,17 @@ def sanitize_llr(llr, clip: float = LLR_CLIP,
     if policy not in ("zero", "raise"):
         raise ValueError(f"sanitize policy must be 'zero', 'raise' or "
                          f"'off', got {policy!r}")
+    # Fast check: a clean push has nothing to fix exactly when every value
+    # lies in [-c, c]. NaN carries through max/min and fails the test, as
+    # does ±Inf. ``c`` is the largest finite float32 not above ``clip``, so
+    # the check passes nothing the scan below flags, whether the scan's
+    # comparison runs in float32 (a Python float clip) or in float64 (a
+    # numpy float64 clip). Whatever fails the check goes to the exact scan.
+    c = np.float32(clip)
+    if c == np.inf or float(c) > clip:
+        c = np.nextafter(c, np.float32(-np.inf))
+    if arr.size == 0 or (arr.max() <= c and arr.min() >= -c):
+        return arr, 0
     finite = np.isfinite(arr)
     bad = ~finite | (np.abs(arr) > clip)
     n_bad = int(bad.sum())

@@ -219,9 +219,10 @@ class DecodeServer:
                   ``launch_attempt``/``degrade``), ``retire`` (around
                   ``retire_wait``), ``evacuate``, ``readmit``,
                   ``breaker_probe``; async ``inflight``; instants
-                  ``retry``, ``breaker_open``, ``breaker_close``; counter
+                  ``retry``, ``breaker_open``, ``breaker_close``; counters
                   ``d2h_prefetch`` (launches whose copy back started at
-                  dispatch). None
+                  dispatch) and ``sanitize_scans`` (pushes the sanitizer
+                  scrubbed or refused, each after its full scan). None
                   (default) resolves to the process-global tracer — a
                   pay-nothing no-op unless ``repro.obs.set_tracer``
                   installed one.
@@ -392,15 +393,22 @@ class DecodeServer:
                                 sid=session.sid) from None
         try:
             session.ctx.check_shape(arr)
+            n_bad = 0
             if self.sanitize != "off":
-                arr, n_bad = sanitize_llr(arr, self.llr_clip, self.sanitize)
-            else:
-                n_bad = 0
+                try:
+                    arr, n_bad = sanitize_llr(arr, self.llr_clip,
+                                              self.sanitize)
+                except ValueError:
+                    # the 'raise' policy refused it after the full scan
+                    self.trace.count("sanitize_scans")
+                    raise
         except ValueError as e:
             self._strike(session, str(e))
             raise PoisonedInput(f"session {session.sid}: {e}",
                                 sid=session.sid) from None
         if n_bad:
+            # the push failed sanitize_llr's clean check: the full scan ran
+            self.trace.count("sanitize_scans")
             # sanitized to safety — still a strike (a tenant repeatedly
             # sending poison gets quarantined even under 'zero' policy)
             bm = self.metrics.bucket(session.bucket.id)

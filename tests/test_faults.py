@@ -22,6 +22,7 @@ from conftest import noisy_llr
 from repro.core import (DecoderConfig, FrameSpec, LLR_CLIP, STD_K7,
                         make_decoder, sanitize_llr, stream_decode)
 from repro.core.stream import make_stream_decoder
+from repro.obs import Tracer
 from repro.serve import (Backpressure, DecodeServer, PlanCache,
                          PoisonedInput, ServeError, ServerFull,
                          SessionQuarantined)
@@ -64,6 +65,117 @@ def test_sanitize_llr_policies(rng):
         sanitize_llr(bad, policy="raise")
     out, n = sanitize_llr(bad, policy="off")
     assert n == 0 and out is bad
+
+
+def _sanitize_six_pass(llr, clip=LLR_CLIP, policy="zero"):
+    """The exact six-pass scan (isfinite, abs, compare, invert, or, sum)
+    with no clean check in front: the reference sanitize_llr's answers
+    are held to, bit for bit."""
+    arr = np.asarray(llr, np.float32)
+    finite = np.isfinite(arr)
+    bad = ~finite | (np.abs(arr) > clip)
+    n_bad = int(bad.sum())
+    if n_bad == 0:
+        return arr, 0
+    if policy == "raise":
+        raise ValueError(
+            f"{n_bad} non-finite or out-of-range (|llr| > {clip:g}) "
+            f"values in a push of {arr.size}")
+    out = np.where(finite, np.clip(arr, -clip, clip), np.float32(0.0))
+    return out.astype(np.float32, copy=False), n_bad
+
+
+def _assert_matches_six_pass(llr, clip=LLR_CLIP):
+    """sanitize_llr == the six-pass scan under 'zero' and 'raise': the
+    same n_bad, output bits and refusal; a clean float32 push comes back
+    as the very input object."""
+    for policy in ("zero", "raise"):
+        try:
+            want, want_err = _sanitize_six_pass(llr, clip, policy), None
+        except ValueError as e:
+            want, want_err = None, str(e)
+        try:
+            got, got_err = sanitize_llr(llr, clip, policy), None
+        except ValueError as e:
+            got, got_err = None, str(e)
+        assert got_err == want_err
+        if want is None:
+            continue
+        (out, n_bad), (ref, ref_bad) = got, want
+        assert n_bad == ref_bad
+        assert out.dtype == np.float32 and out.shape == ref.shape
+        assert out.tobytes() == ref.tobytes()
+        if n_bad == 0 and isinstance(llr, np.ndarray) \
+                and llr.dtype == np.float32:
+            assert out is llr
+
+
+def _at(value, where, n=64):
+    out = np.linspace(-3.0, 3.0, n, dtype=np.float32)
+    out[{"first": 0, "middle": n // 2, "last": n - 1}[where]] = value
+    return out
+
+
+_F32_TENTH = np.float32(0.1)
+_SANITIZE_CASES = {
+    **{f"{name}_{where}": (lambda v=v, w=where: (_at(v, w), LLR_CLIP))
+       for name, v in (("nan", np.nan), ("pinf", np.inf),
+                       ("ninf", -np.inf))
+       for where in ("first", "middle", "last")},
+    "at_clip": lambda: (np.array([LLR_CLIP, -LLR_CLIP, 0.5],
+                                 np.float32), LLR_CLIP),
+    "above_clip": lambda: (np.array(
+        [0.5, np.nextafter(np.float32(LLR_CLIP), np.inf)], np.float32),
+        LLR_CLIP),
+    "below_neg_clip": lambda: (np.array(
+        [np.nextafter(np.float32(-LLR_CLIP), -np.inf), 0.5], np.float32),
+        LLR_CLIP),
+    "neg_zero": lambda: (np.array([-0.0, 0.0, -0.0], np.float32), LLR_CLIP),
+    "empty": lambda: (np.zeros((0, 2), np.float32), LLR_CLIP),
+    "m2_clean": lambda: (np.arange(-8, 8, dtype=np.float32).reshape(8, 2),
+                         LLR_CLIP),
+    "m2_poisoned": lambda: (np.array([[1.0, np.nan], [2e6, -3.0]],
+                                     np.float32), LLR_CLIP),
+    "m3_clean": lambda: (np.linspace(-9, 9, 12, dtype=np.float32)
+                         .reshape(4, 3), LLR_CLIP),
+    "m3_poisoned": lambda: (np.array([[1.0, 2.0, -np.inf],
+                                      [-2e7, 0.0, 4.0]], np.float32),
+                            LLR_CLIP),
+    "float64_clean": lambda: (np.array([1e6 + 0.01, -1.5, 0.0]), LLR_CLIP),
+    "float64_poisoned": lambda: (np.array([1e6 + 1.0, np.nan, -1.5]),
+                                 LLR_CLIP),
+    # 0.1 is not a float32: values on either side of float32(0.1), with
+    # the threshold as a Python float (the scan compares in float32) and
+    # as a numpy float64 (the scan compares in float64)
+    **{f"tenth_{kind}_{side}": (lambda c=c, d=d: (np.array(
+        [0.0, np.nextafter(_F32_TENTH, d * np.inf) if d else _F32_TENTH,
+         -0.05], np.float32), c))
+       for kind, c in (("py", 0.1), ("f64", np.float64(0.1)))
+       for side, d in (("below", -1), ("at", 0), ("above", 1))},
+    "clip_inf_with_inf": lambda: (np.array([1.0, np.inf], np.float32),
+                                  np.inf),
+    "clip_inf_finite": lambda: (np.array([3e38, -3e38], np.float32),
+                                np.inf),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SANITIZE_CASES))
+def test_sanitize_llr_matches_six_pass_scan(case):
+    """The clean check (one max, one min) engages only where the old scan
+    finds nothing: every answer is the scan's, bit for bit."""
+    llr, clip = _SANITIZE_CASES[case]()
+    _assert_matches_six_pass(llr, clip)
+
+
+@given(st.integers(0, 2**32 - 1), st.sampled_from(["nan", "inf", "huge"]))
+def test_sanitize_llr_matches_six_pass_scan_random(seed, mode):
+    """Random positions poisoned in a noisy (m, 2) push, and the clean
+    push itself: sanitize_llr answers as the six-pass scan does."""
+    rng = np.random.default_rng(seed)
+    llr = noisy_llr(rng.integers(0, 2, 256), STD_K7, 3.0, rng)
+    _assert_matches_six_pass(llr)
+    _assert_matches_six_pass(_poison(llr, rng, mode,
+                                     frac=float(rng.uniform(0.001, 0.5))))
 
 
 @given(st.integers(0, 2**32 - 1), st.sampled_from(["nan", "inf", "huge"]))
@@ -195,6 +307,47 @@ def test_server_raise_policy_rejects_without_absorbing(rng):
     srv.push(sid, llr)
     got = srv.close_session(sid)[:n]
     assert np.array_equal(got, stream_decode(cfg, llr, n, chunk_frames=4))
+
+
+def test_server_counts_sanitize_scans(rng):
+    """``sanitize_scans`` reads 0 on clean pushes and counts each push
+    the sanitizer scrubbed (under 'zero') or refused (under 'raise');
+    a push refused for its shape is no scan."""
+    cfg = DecoderConfig(spec=SPEC)
+    per = 4 * SPEC.f
+    llr = noisy_llr(rng.integers(0, 2, per), STD_K7, 3.0, rng)
+    trace = Tracer()
+    srv = DecodeServer(cache=PlanCache(), quarantine_after=10, trace=trace)
+    sid = srv.open_session(cfg, chunk_frames=4)
+
+    def scans():
+        return trace.counters().get("sanitize_scans", 0)
+
+    for _ in range(3):
+        srv.push(sid, llr)
+        srv.step()
+    assert scans() == 0
+    for i, value in enumerate((np.nan, np.inf, -np.inf, 2 * LLR_CLIP)):
+        bad = llr.copy()
+        bad[5, 1] = value
+        srv.push(sid, bad)
+        srv.step()
+        assert scans() == i + 1
+    srv.close_session(sid)
+
+    strict = DecodeServer(cache=PlanCache(), sanitize="raise",
+                          quarantine_after=10, trace=Tracer())
+    sid = strict.open_session(cfg, chunk_frames=4)
+    strict.push(sid, llr)
+    with pytest.raises(PoisonedInput):
+        strict.push(sid, np.zeros((per, 3), np.float32))
+    assert strict.trace.counters().get("sanitize_scans", 0) == 0
+    bad = llr.copy()
+    bad[0, 0] = np.nan
+    with pytest.raises(PoisonedInput, match="non-finite"):
+        strict.push(sid, bad)
+    assert strict.trace.counters()["sanitize_scans"] == 1
+    strict.close_session(sid)
 
 
 def _run_faulted_server(rng, faults, n_chunks=3, **server_kw):
